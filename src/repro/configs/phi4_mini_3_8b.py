@@ -17,6 +17,7 @@ CONFIG = ModelConfig(
             activation="swiglu",
         ),
     ),
+    tie_embeddings=True,
     remat="full",
 )
 

@@ -37,7 +37,7 @@ class OdinConfig:
     signed_activations: bool = True       # False after ReLU (paper's CNN case)
     round_popcount: bool = False          # model 8-bit S_TO_B output rounding
     use_pallas: bool = False              # sc mode: fused kernel vs jnp reference
-    interpret: bool = True                # Pallas interpret mode (CPU container)
+    interpret: bool | None = None         # Pallas interpret mode; None ⇒ off the TPU
     lut_seed: int = 0
     # SC accumulation granularity.  0 ⇒ one full MUX tree over K (the naive
     # reading of the paper — at K ≳ stream_len the 1/K̂ subsampling leaves

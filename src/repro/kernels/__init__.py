@@ -1,5 +1,6 @@
-# Pallas TPU kernels for the paper's compute hot-spots (validated with
-# interpret=True on CPU; BlockSpecs sized for the TPU memory hierarchy):
+# Pallas TPU kernels for the paper's compute hot-spots (compiled on a TPU,
+# interpreted elsewhere — kernels/backend.py; BlockSpecs sized for the TPU
+# memory hierarchy):
 #   sc_mac   — fused B→S → AND → MUX-tree → popcount stochastic GEMM (§IV-B.1)
 #   int8_mm  — int8×int8→int32 MXU GEMM + dequant epilogue (expected surrogate)
 #   act_pool — fused 8-bit ReLU + p×p max-pool (§IV-B.2 add-on logic blocks)

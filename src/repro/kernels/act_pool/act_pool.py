@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 __all__ = ["act_pool_kernel", "act_pool_pallas_call"]
 
 
@@ -56,7 +58,7 @@ def act_pool_pallas_call(
     block_c: int = 8,
     act: str = "relu",
     pool_kind: str = "max",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, H, W, C = x.shape
     assert H % pool == 0 and W % pool == 0, (H, W, pool)
@@ -69,5 +71,5 @@ def act_pool_pallas_call(
         in_specs=[pl.BlockSpec((1, H, W, block_c), lambda b, c: (b, 0, 0, c))],
         out_specs=pl.BlockSpec((1, H // pool, W // pool, block_c), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, H // pool, W // pool, C), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

@@ -13,7 +13,7 @@ __all__ = ["act_pool"]
 
 @functools.partial(jax.jit, static_argnames=("pool", "act", "pool_kind", "interpret"))
 def act_pool(x: jax.Array, *, pool: int = 2, act: str = "relu",
-             pool_kind: str = "max", interpret: bool = True) -> jax.Array:
+             pool_kind: str = "max", interpret: bool | None = None) -> jax.Array:
     """int32 [B,H,W,C] → int32 [B,H/p,W/p,C]: 8-bit act then p×p pooling.
 
     ``act``: relu | tanh (8-bit LUT form); ``pool_kind``: max | avg — the

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 __all__ = ["int8_mm_kernel", "int8_mm_pallas_call"]
 
 
@@ -58,7 +60,7 @@ def int8_mm_pallas_call(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     M, K = a.shape
     _, N = w.shape
@@ -77,5 +79,5 @@ def int8_mm_pallas_call(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, w, scale_a, scale_w)

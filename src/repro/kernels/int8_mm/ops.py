@@ -28,7 +28,7 @@ def _pad(x, axis, mult):
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret"))
 def int8_mm_pallas(a, w, scale_a, scale_w, *, block_m=128, block_n=128,
-                   block_k=128, interpret=True):
+                   block_k=128, interpret=None):
     """a int8 [M,K], w int8 [K,N], scales f32 [M]/[N] → f32 [M,N]."""
     M, K = a.shape
     _, N = w.shape
@@ -43,7 +43,7 @@ def int8_mm_pallas(a, w, scale_a, scale_w, *, block_m=128, block_n=128,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_matmul(x: jax.Array, w: jax.Array, *, interpret: bool = True) -> jax.Array:
+def int8_matmul(x: jax.Array, w: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """fp [M,K] @ fp [K,N] through symmetric int8 quantization (per-row/col)."""
     amax_x = jnp.maximum(jnp.abs(x).max(axis=1, keepdims=True), 1e-12)
     amax_w = jnp.maximum(jnp.abs(w).max(axis=0, keepdims=True), 1e-12)

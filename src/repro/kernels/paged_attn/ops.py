@@ -1,8 +1,8 @@
 """jit'd entry point for paged decode attention.
 
 ``paged_attention(q, k_pool, v_pool, tables, lengths)`` is the op the serving
-decode path calls per layer: GQA head grouping, kernel dispatch, and the
-interpret-mode fallback so tier-1 tests run on CPU.  ``use_kernel=False``
+decode path calls per layer: GQA head grouping, kernel dispatch (interpreted
+off the TPU, so tier-1 tests run on CPU).  ``use_kernel=False``
 routes to the pure-jnp oracle (ref.py) for debugging.
 
 ``q`` may carry a small leading query axis (``[B, Q, H, D]``, the speculative
@@ -34,7 +34,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *, window: int = 0,
     token* (each query's K/V must already be written; query ``j`` of Q sits
     at absolute position ``lengths - Q + j`` and attends causally).
     ``kv_scale`` set ⇒ pools hold fixed-point int8 (values/kv_scale).
-    ``interpret=None`` picks compiled on TPU, interpreter everywhere else.
+    ``interpret=None`` picks compiled on TPU, interpreter everywhere else
+    (``repro.kernels.backend.resolve_interpret``).
     """
     if q.ndim == 3:
         B, H, D = q.shape
@@ -55,8 +56,6 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *, window: int = 0,
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
     if use_kernel:
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         o = paged_attn_pallas_call(qt, k_pool, v_pool, tables, lengths,
                                    window=window, kv_scale=kv_scale,
                                    q_len=Q, interpret=interpret)

@@ -8,14 +8,23 @@ compiled step, so decode reads K/V blocks in place — no dense
 scales with ``n_blocks·block_size`` (≈ active tokens) instead of
 ``slots × max_len``.
 
-Layout (the standard TPU paged-attention shape):
+Layout:
 
-* grid ``(B, H_kv, n_pages)`` with the page axis innermost — the online
-  softmax state (m, l, acc) lives in VMEM scratch carried across pages;
+* grid ``(B, n_pages)`` with the page axis innermost — the online softmax
+  state (m, l, acc) for every kv head lives in VMEM scratch carried across
+  pages;
 * ``lengths [B]`` and ``tables [B, n_pages]`` are **scalar-prefetched**: the
   K/V BlockSpec index maps read ``tables[b, i]`` to pull page ``i`` of
-  sequence ``b`` from the pool, one ``[block_size, d_head]`` tile per step
-  (the Pallas pipeline turns those into the HBM→VMEM block DMAs);
+  sequence ``b`` from the pool as one ``[block_size, H_kv, d_head]`` tile —
+  one DMA per page for all kv heads.  The tile's last two dims are the
+  pool's own ``(H_kv, d_head)``, which is what Mosaic's block-shape rule
+  asks for;
+* all kv heads are scored in one pass: the query tile is flattened to rows
+  ``h·Q·G + j`` and the page to columns ``t·H_kv + h'``; one
+  ``[H_kv·Q·G, D]·[bs·H_kv, D]ᵀ`` MXU product scores every row against every
+  column, and a head mask (``h == h'``) keeps each row on its own kv head.
+  The masked columns cost ``H_kv``× the flops of a per-head product — free
+  for decode, which is bound by the page DMA, not the MXU;
 * pages past a sequence's length — and, under a sliding window, pages wholly
   below it — are skipped via ``pl.when``; partially-valid pages mask by
   absolute position, so stale rows from a block's previous owner are
@@ -24,16 +33,16 @@ Layout (the standard TPU paged-attention shape):
   the kernel reads half the bytes per page and rescales after the load.
 
 Multi-token queries (``q_len > 1``, speculative verify): the query tile packs
-``Q`` in-flight tokens — query row ``q·G + g`` sits at absolute position
-``length - Q + q`` and is causally masked against the page axis per row, so
-one kernel pass scores a whole draft (each draft token sees the committed
-prefix *and* the earlier draft rows, which its forward already wrote into the
-slot's tail blocks).  ``q_len == 1`` reduces exactly to the decode case.
+``Q`` in-flight tokens — row ``q·G + g`` of a head's tile sits at absolute
+position ``length - Q + q`` and is causally masked against the page axis per
+row, so one kernel pass scores a whole draft.  ``q_len == 1`` reduces exactly
+to the decode case.
 
-Per-tile VMEM at the ``block_size=16, d_head=128`` default: q Q·1 KB + k/v
-2×4 KB (int8) + acc/m/l ≈ Q·1 KB ≪ budget; arithmetic is one
-``[Q·G, bs]·[bs, D]`` MXU pass per page.  ``interpret=True`` runs the same
-kernel on CPU (tier-1).
+VMEM at phi4-mini widths (``H_kv=8, d_head=128, block_size=16``) and the
+widest verify tile (``Q = K+1 = 5`` drafts, ``G = 3`` ⇒ 120 query rows): K/V
+tiles 2×32 KB (bf16) double-buffered, q/out 2×30 KB, acc 60 KB, scores
+``120×128`` f32 60 KB — under 0.5 MB of the scoped-VMEM budget.
+Off the TPU the same kernel runs in the interpreter (tier-1 tests).
 """
 from __future__ import annotations
 
@@ -45,6 +54,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 __all__ = ["paged_attn_kernel", "paged_attn_pallas_call"]
 
 NEG_INF = -1e30
@@ -52,17 +63,21 @@ NEG_INF = -1e30
 
 def paged_attn_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
                       m_ref, l_ref, acc_ref, *, block_size: int, n_pages: int,
-                      window: int, scale: float, kv_scale, q_len: int,
-                      n_groups: int):
-    """One (sequence b, kv-head h, page i) grid step of online-softmax GQA.
+                      n_kv: int, window: int, scale: float, kv_scale,
+                      q_len: int, n_groups: int):
+    """One (sequence b, page i) grid step of online-softmax GQA, all heads.
 
-    q_ref [1,1,Q·G,D] · k_ref/v_ref [1,bs,1,D] (page ``tables[b, i]`` of the
-    pool) → o_ref [1,1,Q·G,D]; m/l/acc scratch carry the softmax state over
-    the page axis.  Query row ``q·G + g`` is query token ``q`` at absolute
-    position ``length - Q + q`` (``Q = q_len``; Q == 1 is plain decode).
+    q_ref [1, H_kv·Q·G, D] · k_ref/v_ref [1, bs, H_kv, D] (page
+    ``tables[b, i]`` of the pool) → o_ref [1, H_kv·Q·G, D]; m/l/acc scratch
+    carry the softmax state over the page axis.  Row ``h·Q·G + q·G + g`` is
+    kv head ``h``, query token ``q`` at absolute position ``length - Q + q``
+    (``Q = q_len``; Q == 1 is plain decode).
     """
-    b, i = pl.program_id(0), pl.program_id(2)
+    b, i = pl.program_id(0), pl.program_id(1)
     length = lengths_ref[b]
+    qg = q_len * n_groups
+    rows = n_kv * qg
+    cols = block_size * n_kv
 
     @pl.when(i == 0)
     def _init():
@@ -79,21 +94,23 @@ def paged_attn_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [Q·G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if kv_scale is not None:                             # int8 pool dequant
+        q = q_ref[0].astype(jnp.float32)                      # [rows, D]
+        # [bs, H_kv, D] → [bs·H_kv, D]: column t·H_kv + h is row t, head h
+        k = k_ref[0].astype(jnp.float32).reshape(cols, -1)
+        v = v_ref[0].astype(jnp.float32).reshape(cols, -1)
+        if kv_scale is not None:                              # int8 pool dequant
             k = k * (1.0 / kv_scale)
             v = v * (1.0 / kv_scale)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [Q·G, bs]
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        # per-row causal limit: row q·G+g is the query at length - Q + q
-        q_pos = length - q_len + jax.lax.broadcasted_iota(
-            jnp.int32, (q_len * n_groups, 1), 0) // n_groups
-        ok = pos <= q_pos
+            preferred_element_type=jnp.float32) * scale       # [rows, cols]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        pos = i * block_size + col // n_kv
+        # per-row causal limit: row h·Q·G + q·G + g is the query at
+        # length - Q + q
+        q_pos = length - q_len + (row % qg) // n_groups
+        ok = jnp.logical_and(pos <= q_pos, col % n_kv == row // qg)
         if window:
             ok = jnp.logical_and(ok, pos > q_pos - window)
         s = jnp.where(ok, s, NEG_INF)
@@ -112,8 +129,8 @@ def paged_attn_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(i == n_pages - 1)
     def _finish():
         # length == 0 (idle slot) leaves l at 0 → output 0, never NaN
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attn_pallas_call(
@@ -126,38 +143,39 @@ def paged_attn_pallas_call(
     window: int = 0,
     kv_scale=None,           # pool is int8 fixed-point with this scale
     q_len: int = 1,          # Q query tokens packed per sequence
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, Hkv, QG, D = q.shape
     if QG % q_len:
         raise ValueError(f"query tile {QG} not a multiple of q_len {q_len}")
     bs = k_pool.shape[1]
     n_pages = tables.shape[1]
-    scale = 1.0 / np.sqrt(D)
+    rows = Hkv * QG
     kernel = functools.partial(
-        paged_attn_kernel, block_size=bs, n_pages=n_pages, window=window,
-        scale=scale, kv_scale=kv_scale, q_len=q_len, n_groups=QG // q_len)
+        paged_attn_kernel, block_size=bs, n_pages=n_pages, n_kv=Hkv,
+        window=window, scale=1.0 / np.sqrt(D), kv_scale=kv_scale,
+        q_len=q_len, n_groups=QG // q_len)
+    page = lambda b, i, lens, tabs: (tabs[b, i], 0, 0, 0)
+    tile = lambda b, i, lens, tabs: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_pages),
+        grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, QG, D), lambda b, h, i, lens, tabs: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, i, lens, tabs: (tabs[b, i], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, i, lens, tabs: (tabs[b, i], 0, h, 0)),
+            pl.BlockSpec((1, rows, D), tile),
+            pl.BlockSpec((1, bs, Hkv, D), page),
+            pl.BlockSpec((1, bs, Hkv, D), page),
         ],
-        out_specs=pl.BlockSpec((1, 1, QG, D),
-                               lambda b, h, i, lens, tabs: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, D), tile),
         scratch_shapes=[
-            pltpu.VMEM((QG, 1), jnp.float32),     # m: running max
-            pltpu.VMEM((QG, 1), jnp.float32),     # l: running denominator
-            pltpu.VMEM((QG, D), jnp.float32),     # acc: running numerator
+            pltpu.VMEM((rows, 1), jnp.float32),     # m: running max
+            pltpu.VMEM((rows, 1), jnp.float32),     # l: running denominator
+            pltpu.VMEM((rows, D), jnp.float32),     # acc: running numerator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, QG, D), q.dtype),
-        interpret=interpret,
-    )(lengths, tables, q, k_pool, v_pool)
+        out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(lengths, tables, q.reshape(B, rows, D), k_pool, v_pool)
+    return out.reshape(B, Hkv, QG, D)

@@ -47,7 +47,7 @@ def sc_matmul_pallas(
     selects: jax.Array,
     spec: sc.StreamSpec = sc.StreamSpec(),
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_m: int = 8,
     block_n: int = 8,
     max_tree_k: int = 2048,

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 __all__ = ["sc_mac_kernel", "sc_mac_pallas_call"]
 
 
@@ -110,7 +112,7 @@ def sc_mac_pallas_call(
     block_m: int,
     block_n: int,
     block_k: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Launch the kernel over a (M/bm, N/bn, K̂/bk) grid.  Returns int32 [M, N].
 
@@ -138,5 +140,5 @@ def sc_mac_pallas_call(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, w, ranks_a, ranks_w, selects)

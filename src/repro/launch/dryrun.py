@@ -190,8 +190,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False, mesh=None,
     mem["total_bytes"] = (mem["argument_bytes"] + mem["output_bytes"]
                           + mem["temp_bytes"] - mem["alias_bytes"])
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax < 0.5 returns one dict per computation
-        ca = ca[0] if ca else {}
     cost = {"xla_flops_once": float(ca.get("flops", -1.0)),
             "xla_bytes_once": float(ca.get("bytes accessed", -1.0))}
 

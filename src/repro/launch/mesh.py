@@ -42,10 +42,8 @@ def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import (launch/dryrun.py does)."
         )
-    if hasattr(jax.sharding, "AxisType"):   # jax ≥ 0.5
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto, devices=devs[:n])
-    return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devs[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs.base import ShapeConfig
 from repro.launch import specs as specs_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import lm, registry
 from repro.nn import module as nnmod
@@ -291,6 +292,7 @@ def main():
     ap.add_argument("--heartbeat-ms", type=float, default=None,
                     help="idle-stream heartbeat period")
     args = ap.parse_args()
+    use_compile_cache()
     if args.fault_plan and not args.scenario:
         ap.error("--fault-plan requires --scenario (fault injection is bench/"
                  "test-mode only)")

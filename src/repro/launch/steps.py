@@ -23,21 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax ≥ 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (kwarg renamed across jax)."""
-    try:
-        return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from repro.configs.base import BlockConfig, ModelConfig
 from repro.models import lm
 from repro.nn.attention import POOL_LEAVES, init_paged_cache
@@ -801,12 +786,13 @@ def make_dp_train_step(cfg: ModelConfig, mesh: Mesh,
         return jax.tree.map(lambda _: spec, tree)
 
     def dp_step(params, opt_state, batch, key):
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(specs_like(params, rep), specs_like(opt_state, rep),
                       specs_like(batch, batch_spec), rep),
             out_specs=(specs_like(params, rep), specs_like(opt_state, rep),
                        {"loss": rep}),
+            check_vma=False,
         )
         return fn(params, opt_state, batch, key)
 

@@ -65,7 +65,10 @@ def materialize(tree, key):
     """ParamSpec tree → initialized array tree (deterministic per-leaf keys)."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(treedef, [_init_one(s, k) for s, k in zip(leaves, keys)])
+    # one leaf at a time: queued eager draws would hold several float32
+    # transients at once (phi4-mini's stacked MLP weight alone is 3.2 GB)
+    return jax.tree.unflatten(treedef, [_init_one(s, k).block_until_ready()
+                                        for s, k in zip(leaves, keys)])
 
 
 def logical_to_pspec(axes: Sequence[Optional[str]], rules: Dict[str, Optional[str]]) -> P:

@@ -21,7 +21,11 @@ an **async token stream**.  The contract it adds on top of the engine:
   flushing in-flight streams while late submissions get a typed
   :class:`ShuttingDown`; per-request deadlines propagate through the
   engine's watch list; idle streams emit heartbeats so slow queues are
-  distinguishable from dead connections.
+  distinguishable from dead connections.  An exception out of
+  ``engine.step()`` (a device or compile error) ends every open stream with
+  a ``failed``/``engine_error`` terminal event and is re-raised to whoever
+  awaits the driver or calls :meth:`shutdown` — a dead engine never leaves a
+  consumer waiting.
 
 Single-threaded by construction: asyncio's cooperative scheduling means
 ``submit``/``cancel`` can call the synchronous engine *directly* — the
@@ -173,6 +177,7 @@ class FrontDoor:
         self._wake = asyncio.Event()
         self._driver: Optional[asyncio.Task] = None
         self._closed = False
+        self._failure: Optional[BaseException] = None
         # install hooks (chained / restored by aclose)
         self._prev_on_token = engine.on_token
         engine.on_token = self._on_token
@@ -241,6 +246,9 @@ class FrontDoor:
         preserves bit-identical tokens vs. an offline run).
         """
         eng = self.engine
+        if self._failure is not None:
+            raise RuntimeError(f"request {req.rid}: the engine failed"
+                               ) from self._failure
         now = eng._now()
         if self._draining or eng.draining or self._closed:
             raise self._reject(
@@ -298,8 +306,9 @@ class FrontDoor:
                     return
         finally:
             # consumer abandoned the stream (disconnect, aclose, timeout
-            # wrapper): cancel is idempotent, a no-op for terminal requests
-            if not req.terminal:
+            # wrapper): cancel is idempotent, a no-op for terminal requests;
+            # a failed engine is left as it died
+            if not req.terminal and self._failure is None:
                 if self.engine.cancel(req.rid, reason="disconnect"):
                     self.stats["disconnect_cancels"] += 1
                 self._wake.set()
@@ -316,8 +325,7 @@ class FrontDoor:
         try:
             while not self._closed:
                 if eng.sched.has_work:
-                    eng.step()
-                    self._route_done()
+                    self._step()
                     self._heartbeats()
                     # yield so consumers drain their queues between steps
                     await asyncio.sleep(0)
@@ -331,6 +339,27 @@ class FrontDoor:
                         self._heartbeats(force_idle=True)
         except asyncio.CancelledError:
             pass
+
+    def _step(self) -> None:
+        """One engine step plus terminal-event routing.  If the step raises,
+        every open stream gets a ``failed`` terminal event before the
+        exception propagates."""
+        try:
+            self.engine.step()
+        except Exception as exc:
+            self._fail_streams(exc)
+            raise
+        self._route_done()
+
+    def _fail_streams(self, exc: BaseException) -> None:
+        self._failure = exc
+        self._route_done()
+        now = self.engine._now()
+        for h in self._streams.values():
+            if not h.req.terminal:
+                h.queue.put_nowait(DoneEvent(
+                    rid=h.req.rid, t=now, state=RequestState.FAILED.value,
+                    finish_reason="engine_error", n_tokens=h.req.n_generated))
 
     def _route_done(self) -> None:
         """Push a DoneEvent for every newly-terminal request.
@@ -370,25 +399,29 @@ class FrontDoor:
         """Graceful SIGTERM semantics: stop admitting (late submits raise
         :class:`ShuttingDown`), cancel never-admitted queued requests with
         reason ``"drain"``, then step until every in-flight stream has
-        flushed its terminal event."""
+        flushed its terminal event.  Re-raises an engine failure, whether it
+        happened in the driver or during the drain."""
         eng = self.engine
         self._draining = True
         eng.draining = True
-        now = eng._now()
-        for _, _, req in list(eng.sched.waiting):
-            if req.t_admit is None:
-                eng.cancel(req.rid, reason="drain")
-        self._route_done()
-        await asyncio.sleep(0)
-        while eng.sched.has_work:
-            eng.step()
-            self._route_done()
-            await asyncio.sleep(0)
-        self._route_done()
-        # let consumers drain their final events before the driver stops
-        for _ in range(3):
-            await asyncio.sleep(0)
-        await self.aclose()
+        try:
+            if self._failure is None:
+                for _, _, req in list(eng.sched.waiting):
+                    if req.t_admit is None:
+                        eng.cancel(req.rid, reason="drain")
+                self._route_done()
+                await asyncio.sleep(0)
+                while eng.sched.has_work and self._failure is None:
+                    self._step()
+                    await asyncio.sleep(0)
+                self._route_done()
+            # let consumers drain their final events before the driver stops
+            for _ in range(3):
+                await asyncio.sleep(0)
+        finally:
+            await self.aclose()
+        if self._failure is not None:
+            raise self._failure
 
     async def aclose(self) -> None:
         """Detach from the engine: stop the driver and restore the hooks.
@@ -405,6 +438,8 @@ class FrontDoor:
                 await self._driver
             except asyncio.CancelledError:
                 pass
+            except Exception:
+                pass        # kept in self._failure; shutdown() re-raises it
             self._driver = None
         self.engine.on_token = self._prev_on_token
         self.engine.sched.victim_key = self._prev_victim_key

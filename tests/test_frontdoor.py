@@ -466,3 +466,49 @@ def test_heartbeats_on_idle_streams(phi4_setup):
     assert first_kind[reqs[1].rid] == "heartbeat"
     _assert_all_terminal(reqs)
     _assert_no_leaks(eng)
+
+
+# ---------------------------------------------------------------- engine failure
+
+@pytest.mark.parametrize("fail_at", [0, 2])
+def test_engine_step_failure_ends_every_stream(phi4_setup, fail_at):
+    """A step that raises (a device or compile error) must end every open
+    stream with a terminal ``failed`` event and surface the exception to the
+    driver's awaiter and to ``shutdown()`` — nothing may hang."""
+    eng = _engine(phi4_setup)
+    reqs = make_requests(phi4_setup[0], mixed_spec(4), seed=9)
+    real_step, calls = eng.step, [0]
+
+    def step():
+        calls[0] += 1
+        if calls[0] > fail_at:
+            raise RuntimeError("injected dispatch failure")
+        return real_step()
+
+    eng.step = step
+
+    async def main():
+        fd = FrontDoor(eng, max_queue=16)
+        await fd.start()
+        streams = [fd.submit(r) for r in reqs]
+        outs = await asyncio.wait_for(
+            asyncio.gather(*[_collect(s) for s in streams]), timeout=60)
+        with pytest.raises(RuntimeError, match="injected"):
+            await fd._driver
+        with pytest.raises(RuntimeError, match="engine failed"):
+            fd.submit(make_requests(phi4_setup[0], mixed_spec(1), seed=3)[0])
+        with pytest.raises(RuntimeError, match="injected"):
+            await asyncio.wait_for(fd.shutdown(), timeout=60)
+        return outs
+
+    outs = asyncio.run(main())
+    assert len(outs) == len(reqs)
+    for r, (toks, done, _) in zip(reqs, outs):
+        assert done is not None, f"rid {r.rid} has no terminal event"
+        if done.state != "done":
+            assert done.state == "failed"
+            assert done.finish_reason == "engine_error"
+        assert done.n_tokens == len(toks)
+    assert any(done.state == "failed" for _, done, _ in outs)
+    # shutdown restored the hooks even though it raised
+    assert eng.on_token is None and eng.sched.victim_key is None
