@@ -234,8 +234,9 @@ def main():
     ap.add_argument("--metrics-window", type=float, default=1.0,
                     help="windowed-metrics snapshot period in seconds")
     ap.add_argument("--xla-annotations", action="store_true",
-                    help="wrap each compiled dispatch in a jax.profiler "
-                         "TraceAnnotation (aligns XLA profiles with spans)")
+                    help="with --trace-out, also open a jax.profiler "
+                         "TraceAnnotation serving/<phase> for each host "
+                         "phase (aligns XLA profiles with spans)")
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="default per-request deadline after arrival; past-"
                          "deadline requests finish as TIMEOUT (slot freed "

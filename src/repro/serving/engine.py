@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict
-from contextlib import nullcontext
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -80,6 +80,47 @@ from repro.serving.scheduler import (PrefixCache, PrefixGrant, Request,
 from repro.serving.trace import NULL_TRACER, MetricsRegistry
 
 __all__ = ["ServingEngine"]
+
+# host phase → the EngineStats field its seconds go to; every compiled
+# dispatch's own phase (``mixed``, ``decode``, …) goes to dispatch_launch_s
+_PHASE_FIELDS = {"plan": "host_plan_s", "pack": "host_pack_s",
+                 "tables": "host_tables_s", "sync": "dispatch_sync_s",
+                 "wear": "host_wear_s", "emit": "host_emit_s",
+                 "deliver": "deliver_s", "idle": "idle_wait_s"}
+
+
+class _HostPhase:
+    """``with engine._phase("plan"):`` — one host phase of the loop.
+
+    Always adds the phase's own seconds on the engine clock to its
+    ``EngineStats`` field: a phase nested inside it and any garbage
+    collection counted inside it are taken out, so no second counts twice.
+    The tracer's span of the same name (a shared no-op with tracing off)
+    opens and closes with it."""
+
+    __slots__ = ("eng", "name", "field", "t0", "mark", "span")
+
+    def __init__(self, eng: "ServingEngine", name: str):
+        self.eng = eng
+        self.name = name
+        self.field = _PHASE_FIELDS.get(name, "dispatch_launch_s")
+
+    def __enter__(self):
+        eng = self.eng
+        self.span = eng.tracer.phase(self.name)
+        self.span.__enter__()
+        self.mark = eng._phase_spent + eng.stats.gc_pause_s
+        self.t0 = eng._now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        eng = self.eng
+        st = eng.stats
+        inner = eng._phase_spent + st.gc_pause_s - self.mark
+        dt = max(0.0, eng._now() - self.t0 - inner)
+        setattr(st, self.field, getattr(st, self.field) + dt)
+        eng._phase_spent += dt
+        self.span.__exit__(*exc)
 
 
 class ServingEngine:
@@ -169,14 +210,20 @@ class ServingEngine:
         request lifecycle flows and scheduler/pool decision events into
         (exportable as Perfetto-loadable Chrome trace JSON).  Default None ⇒
         the no-op recorder: every emit site is guarded by ``tracer.enabled``,
-        so the trace-off hot path allocates nothing per dispatch.
+        so the trace-off hot path builds no event, and each host phase
+        costs two clock reads and a float add (``EngineStats.host_*_s``,
+        ``dispatch_*_s``, always on).  A tracer also counts garbage
+        collections (``gc_pause_s``, ``gc_collections``) while attached.
     metrics_window : window length (engine-clock seconds) for the windowed
         metrics registry — TTFT/TPOT/dispatch-wall-time histograms and
         counter deltas are snapshotted per window so long runs report
         p50/p99 over time (``summary()["metrics"]["windows"]``).
-    xla_annotations : wrap each compiled dispatch in a
-        ``jax.profiler.TraceAnnotation`` named ``serving/<kind>`` so XLA
-        profiler timelines line up with the engine's own dispatch spans.
+    xla_annotations : with a ``tracer``, each host phase of the loop also
+        opens a ``jax.profiler.TraceAnnotation`` named ``serving/<phase>``
+        (``plan``, ``pack``, ``tables``, the dispatch's kind, ``sync``,
+        ``wear``, ``emit``; ``deliver``/``idle`` in the front door; ``gc``),
+        so XLA profiler timelines say what the host was doing between and
+        inside dispatches.  Without a tracer nothing is annotated.
     deadline_s / queue_timeout_s : engine-wide defaults stamped onto every
         submitted request that does not carry its own ``deadline`` /
         ``queue_timeout``.  A past-deadline request is released as
@@ -410,7 +457,9 @@ class ServingEngine:
         # site is guarded on tracer.enabled so trace-off costs nothing) and
         # the always-on windowed metrics registry
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.tracer.set_clock(self._now)
+        self.tracer.attach(self._now, stats=self.stats,
+                           annotate=bool(xla_annotations))
+        self._phase_spent = 0.0         # Σ seconds of closed host phases
         self.sched.tracer = self.tracer
         self.pool.tracer = self.tracer
         if self.store is not None:
@@ -419,7 +468,6 @@ class ServingEngine:
         # open the first window at t≈0 so no counter movement predates the
         # baseline (maybe_roll's first call only initializes)
         self.metrics.maybe_roll(self._now(), self._counter_snapshot())
-        self.xla_annotations = bool(xla_annotations)
 
         # ---- robustness substrate ----------------------------------------
         self.deadline_s = deadline_s
@@ -485,12 +533,11 @@ class ServingEngine:
     def _slot_track(slot: int) -> str:
         return f"slot {slot}"
 
-    def _annotate(self, kind: str):
-        """Optional XLA-profiler annotation around a compiled dispatch, so
-        device timelines line up with the engine's own spans."""
-        if self.xla_annotations:
-            return jax.profiler.TraceAnnotation(f"serving/{kind}")
-        return nullcontext()
+    def _phase(self, name: str) -> _HostPhase:
+        """One host phase: ``plan``, ``pack``, ``tables``, a dispatch's kind
+        (its launch), ``sync``, ``wear``, ``emit``; the front door adds
+        ``deliver`` and ``idle``."""
+        return _HostPhase(self, name)
 
     def _counter_snapshot(self) -> Dict[str, float]:
         """Cumulative counters the metrics registry turns into window deltas."""
@@ -1008,10 +1055,11 @@ class ServingEngine:
         chunk_sizes: List[int] = []
         # prefill writes K/V blocks straight into the pool via this row
         # (admission bumped table_version, so the mirror refreshes here)
-        tables = self._refresh_tables()
+        with self._phase("tables"):
+            tables = self._refresh_tables()
         start = start0
         ll = None
-        with self._annotate("prefill"):
+        with self._phase("prefill"):
             while start < ntok:
                 c = min(self.chunk, ntok - start)
                 chunk_toks = jnp.asarray(toks[..., start:start + c][None])
@@ -1028,48 +1076,53 @@ class ServingEngine:
                 self.stats.dispatches += 1
                 chunk_sizes.append(c)
                 start += c
+        with self._phase("sync"):
             jax.block_until_ready(ll)
+            if fresh:
+                ll = np.asarray(ll)          # the first token's logits
         wall = self._now() - t0
-        self.stats.host_syncs += 1
-        self.stats.prefill_time += wall
-        self.stats.prefill_tokens += ntok - start0
-        req.n_prefill_tokens += ntok - start0
-        self.metrics.observe("dispatch_prefill_s", wall)
-        if trace:
-            # chunks are not individually synced, so the dispatch's engine-
-            # clock span is split across chunks proportionally to their rows
-            # (same interpolation philosophy as horizon token timestamps)
-            span = wall
-            track = self._slot_track(req.slot)
-            total = max(1, ntok - start0)
-            self.tracer.flow_event("t", "request", track, req.rid, ts=t0)
-            off, pos = t0, start0
-            for i, c in enumerate(chunk_sizes):
-                dur = span * c / total
-                self.tracer.span(
-                    "prefill-chunk", "dispatch", track, off, dur,
-                    args={"kind": "prefill-chunk", "rid": req.rid,
-                          "slot": req.slot, "start": pos, "rows": c,
-                          "prefix_hit_tokens": start0 if i == 0 else 0,
-                          "host_syncs": 1 if i == len(chunk_sizes) - 1 else 0,
-                          "interpolated": len(chunk_sizes) > 1,
-                          "odin_energy_mj": self.cost_model.energy_mj(c)},
-                    flow=req.rid)
-                off += dur
-                pos += c
-        self._slot_len[req.slot] = ntok
-        # endurance mirror: the replay scattered rows [start0, ntok) into
-        # the request's blocks (shared prefix rows were read, not written)
-        self._record_writes(req, start0, ntok - start0, self._now())
-        if fresh:
-            tok = self._first_token(ll, req)                   # [] or [K]
-            self._emit(req, tok, self._now())
-            pending = tok
-        else:
-            pending = req.generated[-1]
-        self._set_last_tok(req.slot, pending)
-        if self.spec_ngram:
-            self._seed_hist(req)
+        with self._phase("wear"):
+            # endurance mirror: the replay scattered rows [start0, ntok) into
+            # the request's blocks (shared prefix rows were read, not written)
+            self._record_writes(req, start0, ntok - start0, self._now())
+        with self._phase("emit"):
+            self.stats.host_syncs += 1
+            self.stats.prefill_time += wall
+            self.stats.prefill_tokens += ntok - start0
+            req.n_prefill_tokens += ntok - start0
+            self.metrics.observe("dispatch_prefill_s", wall)
+            if trace:
+                # chunks are not individually synced, so the dispatch's
+                # engine-clock span is split across chunks proportionally to
+                # their rows (as horizon token timestamps are interpolated)
+                span = wall
+                track = self._slot_track(req.slot)
+                total = max(1, ntok - start0)
+                self.tracer.flow_event("t", "request", track, req.rid, ts=t0)
+                off, pos = t0, start0
+                for i, c in enumerate(chunk_sizes):
+                    dur = span * c / total
+                    self.tracer.span(
+                        "prefill-chunk", "dispatch", track, off, dur,
+                        args={"kind": "prefill-chunk", "rid": req.rid,
+                              "slot": req.slot, "start": pos, "rows": c,
+                              "prefix_hit_tokens": start0 if i == 0 else 0,
+                              "host_syncs": int(i == len(chunk_sizes) - 1),
+                              "interpolated": len(chunk_sizes) > 1,
+                              "odin_energy_mj": self.cost_model.energy_mj(c)},
+                        flow=req.rid)
+                    off += dur
+                    pos += c
+            self._slot_len[req.slot] = ntok
+            if fresh:
+                tok = self._first_token(ll, req)                   # [] or [K]
+                self._emit(req, tok, self._now())
+                pending = tok
+            else:
+                pending = req.generated[-1]
+            self._set_last_tok(req.slot, pending)
+            if self.spec_ngram:
+                self._seed_hist(req)
 
     # -------------------------------------------------- mixed dispatch path
 
@@ -1129,108 +1182,117 @@ class ServingEngine:
         first token from ``last_logits`` through the same host-side
         ``_first_token`` path as the separate prefill — greedy mixed-on
         streams are bit-identical to mixed-off."""
-        decode, parts = self.sched.pack_mixed(self.mixed_budget, self.chunk)
-        if not decode and not parts:
-            return
-        q_max = max([1] + [c for _, _, c in parts])
-        Q = 1 << (q_max - 1).bit_length()       # pow-2 tile widths, bounded
-        K = self.cfg.n_codebooks
-        tok = np.zeros((self.slots, K, Q) if K > 1 else (self.slots, Q),
-                       np.int32)
-        q_lens = np.zeros(self.slots, np.int32)
-        active = np.zeros(self.slots, bool)
-        dm = np.zeros(self.slots, bool)
-        for r in decode:
-            active[r.slot] = True
-            dm[r.slot] = True
-            q_lens[r.slot] = 1
-            # the pending token is host-resident in the stream — no device
-            # readback of _last_tok needed to build the tile
-            tok[r.slot, ..., -1] = np.asarray(r.generated[-1], np.int32)
-        for r, start, c in parts:
-            active[r.slot] = True
-            q_lens[r.slot] = c
-            tok[r.slot, ..., Q - c:] = np.asarray(
-                r.replay_tokens(), np.int32)[..., start:start + c]
+        with self._phase("pack"):
+            decode, parts = self.sched.pack_mixed(self.mixed_budget,
+                                                  self.chunk)
+            if not decode and not parts:
+                return
+            q_max = max([1] + [c for _, _, c in parts])
+            Q = 1 << (q_max - 1).bit_length()   # pow-2 tile widths, bounded
+            K = self.cfg.n_codebooks
+            tok = np.zeros((self.slots, K, Q) if K > 1 else (self.slots, Q),
+                           np.int32)
+            q_lens = np.zeros(self.slots, np.int32)
+            active = np.zeros(self.slots, bool)
+            dm = np.zeros(self.slots, bool)
+            for r in decode:
+                active[r.slot] = True
+                dm[r.slot] = True
+                q_lens[r.slot] = 1
+                # the pending token is host-resident in the stream — no
+                # device readback of _last_tok needed to build the tile
+                tok[r.slot, ..., -1] = np.asarray(r.generated[-1], np.int32)
+            for r, start, c in parts:
+                active[r.slot] = True
+                q_lens[r.slot] = c
+                tok[r.slot, ..., Q - c:] = np.asarray(
+                    r.replay_tokens(), np.int32)[..., start:start + c]
         t0 = self._now()            # engine clock: metrics ≡ stats ≡ trace
-        tables = self._refresh_tables()
-        key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-        with self._annotate("mixed"):
+        with self._phase("tables"):
+            tables = self._refresh_tables()
+        with self._phase("mixed"):
+            key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
             nxt, last_logits, self.caches = self._mixed_fn()(
                 self.params, self.caches, jnp.asarray(tok),
                 jnp.asarray(self._slot_len), jnp.asarray(q_lens),
                 jnp.asarray(dm), jnp.asarray(active), tables, key,
                 jnp.float32(self.temperature))
+        with self._phase("sync"):
             host = np.asarray(nxt)                   # syncs the step
             ll_host = np.asarray(last_logits) if parts else None
         wall = self._now() - t0
-        dec_rows = len(decode)
-        pre_rows = sum(c for _, _, c in parts)
-        rows = dec_rows + pre_rows
-        # phase-attributed time: the dispatch is one wall, split across the
-        # decode/prefill ledgers proportionally to the rows each contributed
-        self.stats.decode_time += wall * dec_rows / rows
-        self.stats.prefill_time += wall * pre_rows / rows
-        self.metrics.observe("dispatch_mixed_s", wall)
-        self.stats.dispatches += 1
-        self.stats.host_syncs += 1
-        self.stats.mixed_dispatches += 1
-        self.stats.mixed_decode_rows += dec_rows
-        self.stats.mixed_prefill_rows += pre_rows
-        if self.tracer.enabled:
-            self.tracer.span(
-                "mixed", "dispatch", "dispatch", t0, wall,
-                args={"kind": "mixed", "q_tile": Q,
-                      "slots_active": int(active.sum()),
-                      "decode_rows": dec_rows, "prefill_rows": pre_rows,
-                      "tokens": dec_rows, "rows": rows, "host_syncs": 1,
-                      "odin_energy_mj": self.cost_model.energy_mj(rows)})
         now = self._now()
-        if decode:
-            # the decode sampling-key schedule only advances when decode
-            # rows actually rode along (pure-prefill dispatches don't burn
-            # a fold_in index the separate path never would have)
-            self.stats.decode_steps += 1
-            self.stats.decode_dispatches += 1
-            self.stats.active_slot_steps += dec_rows
-            self.stats.slot_steps += self.slots
-            dmj = jnp.asarray(dm).reshape(
-                (self.slots,) + (1,) * (self._last_tok.ndim - 1))
-            self._last_tok = jnp.where(dmj, nxt, self._last_tok)
-            if self.spec_ngram:
-                # speculable ⇒ single codebook, so nxt is [slots, 1]
-                shifted = jnp.concatenate([self._hist[:, 1:], nxt], axis=1)
-                self._hist = jnp.where(jnp.asarray(dm)[:, None], shifted,
-                                       self._hist)
-        for r in decode:
-            self._record_writes(r, int(self._slot_len[r.slot]), 1, now)
-            self._slot_len[r.slot] += 1
-            self.stats.decode_tokens += 1
-            self._emit(r, host[r.slot, ..., 0], now)
-            if r.done:
-                self._complete(r, now)
-        for r, start, c in parts:
-            self._record_writes(r, start, c, now)
-            r.prefill_pos = start + c
-            self._slot_len[r.slot] = r.prefill_pos
-            self.stats.prefill_tokens += c
-            r.n_prefill_tokens += c
-            if r.prefill_pos < r.cached_len:
-                continue                            # more chunks to stage
-            self.sched.finish_prefill(r)
-            if r.n_generated == 0:
-                tok1 = self._first_token(ll_host[r.slot:r.slot + 1], r)
-                self._emit(r, tok1, now)
-                pending = tok1
-            else:
-                # readmitted after a recompute preemption: the pending token
-                # survived host-side, the replay only rebuilt the KV
-                pending = r.generated[-1]
-            self._set_last_tok(r.slot, pending)
-            if self.spec_ngram:
-                self._seed_hist(r)
-            if r.done:
-                self._complete(r, now)
+        with self._phase("wear"):
+            for r in decode:
+                self._record_writes(r, int(self._slot_len[r.slot]), 1, now)
+            for r, start, c in parts:
+                self._record_writes(r, start, c, now)
+        with self._phase("emit"):
+            dec_rows = len(decode)
+            pre_rows = sum(c for _, _, c in parts)
+            rows = dec_rows + pre_rows
+            # phase-attributed time: the dispatch is one wall, split across
+            # the decode/prefill ledgers proportionally to their rows
+            self.stats.decode_time += wall * dec_rows / rows
+            self.stats.prefill_time += wall * pre_rows / rows
+            self.metrics.observe("dispatch_mixed_s", wall)
+            self.stats.dispatches += 1
+            self.stats.host_syncs += 1
+            self.stats.mixed_dispatches += 1
+            self.stats.mixed_decode_rows += dec_rows
+            self.stats.mixed_prefill_rows += pre_rows
+            if self.tracer.enabled:
+                self.tracer.span(
+                    "mixed", "dispatch", "dispatch", t0, wall,
+                    args={"kind": "mixed", "q_tile": Q,
+                          "slots_active": int(active.sum()),
+                          "decode_rows": dec_rows, "prefill_rows": pre_rows,
+                          "tokens": dec_rows, "rows": rows, "host_syncs": 1,
+                          "odin_energy_mj": self.cost_model.energy_mj(rows)})
+            if decode:
+                # the decode sampling-key schedule only advances when decode
+                # rows actually rode along (pure-prefill dispatches don't
+                # burn a fold_in index the separate path never would have)
+                self.stats.decode_steps += 1
+                self.stats.decode_dispatches += 1
+                self.stats.active_slot_steps += dec_rows
+                self.stats.slot_steps += self.slots
+                dmj = jnp.asarray(dm).reshape(
+                    (self.slots,) + (1,) * (self._last_tok.ndim - 1))
+                self._last_tok = jnp.where(dmj, nxt, self._last_tok)
+                if self.spec_ngram:
+                    # speculable ⇒ single codebook, so nxt is [slots, 1]
+                    shifted = jnp.concatenate([self._hist[:, 1:], nxt],
+                                              axis=1)
+                    self._hist = jnp.where(jnp.asarray(dm)[:, None], shifted,
+                                           self._hist)
+            for r in decode:
+                self._slot_len[r.slot] += 1
+                self.stats.decode_tokens += 1
+                self._emit(r, host[r.slot, ..., 0], now)
+                if r.done:
+                    self._complete(r, now)
+            for r, start, c in parts:
+                r.prefill_pos = start + c
+                self._slot_len[r.slot] = r.prefill_pos
+                self.stats.prefill_tokens += c
+                r.n_prefill_tokens += c
+                if r.prefill_pos < r.cached_len:
+                    continue                        # more chunks to stage
+                self.sched.finish_prefill(r)
+                if r.n_generated == 0:
+                    tok1 = self._first_token(ll_host[r.slot:r.slot + 1], r)
+                    self._emit(r, tok1, now)
+                    pending = tok1
+                else:
+                    # readmitted after a recompute preemption: the pending
+                    # token survived host-side, the replay only rebuilt the KV
+                    pending = r.generated[-1]
+                self._set_last_tok(r.slot, pending)
+                if self.spec_ngram:
+                    self._seed_hist(r)
+                if r.done:
+                    self._complete(r, now)
 
     def step(self) -> bool:
         """One engine iteration; returns True while work remains.
@@ -1240,7 +1302,27 @@ class ServingEngine:
         paths, a swap-copy fault downgrades the victim to recompute, a
         NaN-poisoned slot is quarantined by the guarded decode, and clock
         skew is clamped monotone — no fault event ever escapes ``step()``
-        as an exception."""
+        as an exception.
+
+        Host phases tile the step: ``plan``, then the chosen dispatch's own
+        phases (``pack``, ``tables``, its launch, ``sync``, ``wear``,
+        ``emit``), then ``emit`` for the step's closing bookkeeping."""
+        with self._phase("plan"):
+            dispatch = self._plan_step()
+        if dispatch is not None:
+            dispatch()
+        with self._phase("emit"):
+            self.stats.steps += 1
+            if self.degrade is not None:
+                self._observe_degrade(self._now())
+            self.metrics.maybe_roll(self._now(), self._counter_snapshot())
+        return self.sched.has_work
+
+    def _plan_step(self) -> Optional[Callable[[], None]]:
+        """A step up to its dispatch: expiry, faults, the reliability sweep,
+        ``Scheduler.plan``, preemption and resume copies, admissions (a
+        separate prefill dispatches here), and the choice of dispatch,
+        returned to run."""
         now = self._now()
         if self._watched:
             self._expire(now)
@@ -1384,67 +1466,78 @@ class ServingEngine:
             # invariant, so unfaulted co-batched slots stay bit-identical.
             # Mid-prefill slots sit this one step out (the guard has no
             # mixed tile) and resume staging next step.
-            self._decode_guarded_step(active_slots, nan_ev)
-        elif mixed_pending:
+            return partial(self._decode_guarded_step, active_slots, nan_ev)
+        if mixed_pending:
             # ONE dispatch carries decode rows and prefill-chunk rows; the
             # horizon/spec fused paths resume once the prefill burst drains
-            self._dispatch_mixed()
-        elif active_slots:
-            if spec_k:
-                # speculation always rides the fused scan (h == 1 is one
-                # draft→verify→accept step); grant 0 ⇒ the pool cannot cover
-                # the worst-case K+1-row write span — plain single step
-                h = self.sched.grant_horizon(max_h, now,
-                                             self._est_step_time(),
-                                             spec_k=spec_k)
-                if h >= 1:
-                    self._decode_spec_steps(active_slots, h)
-                else:
-                    self._decode_single_step(active_slots)
-            elif self.spec_ngram:
-                # speculation shed by the degradation ladder: plain single
-                # steps keep the n-gram history aligned for the restore
-                self._decode_single_step(active_slots)
-            else:
-                h = 1
-                if max_h > 1:
-                    h = self.sched.grant_horizon(max_h, now,
-                                                 self._est_step_time())
-                if h > 1:
-                    self._decode_horizon_steps(active_slots, h)
-                else:
-                    self._decode_single_step(active_slots)
-        self.stats.steps += 1
-        if self.degrade is not None:
-            self._observe_degrade(self._now())
-        self.metrics.maybe_roll(self._now(), self._counter_snapshot())
-        return self.sched.has_work
+            return self._dispatch_mixed
+        if not active_slots:
+            return None
+        if spec_k:
+            # speculation always rides the fused scan (h == 1 is one
+            # draft→verify→accept step); grant 0 ⇒ the pool cannot cover
+            # the worst-case K+1-row write span — plain single step
+            h = self.sched.grant_horizon(max_h, now, self._est_step_time(),
+                                         spec_k=spec_k)
+            if h >= 1:
+                return partial(self._decode_spec_steps, active_slots, h)
+        elif not self.spec_ngram and max_h > 1:
+            # (speculation shed by the degradation ladder runs plain single
+            # steps, which keep the n-gram history aligned for the restore)
+            h = self.sched.grant_horizon(max_h, now, self._est_step_time())
+            if h > 1:
+                return partial(self._decode_horizon_steps, active_slots, h)
+        return partial(self._decode_single_step, active_slots)
 
     def _decode_single_step(self, active_slots: List[int]) -> None:
         """One ``[slots, 1]`` decode dispatch (the horizon=1 parity baseline)."""
-        trace = self.tracer.enabled
         t0 = self._now()            # engine clock: metrics ≡ stats ≡ trace
-        active = np.zeros(self.slots, bool)
-        active[active_slots] = True
-        tables = self._refresh_tables()  # growth may have extended tables
-        key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-        with self._annotate("decode"):
+        with self._phase("pack"):
+            active = np.zeros(self.slots, bool)
+            active[active_slots] = True
+        with self._phase("tables"):
+            tables = self._refresh_tables()  # growth may have extended them
+        with self._phase("decode"):
+            key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
             nxt, self.caches = self._decode(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 tables, key, jnp.float32(self.temperature))
+        with self._phase("sync"):
             host = np.asarray(nxt)                   # syncs the step
         wall = self._now() - t0
+        now = self._now()
+        with self._phase("wear"):
+            for s in active_slots:
+                self._record_writes(self.sched.running[s],
+                                    int(self._slot_len[s]), 1, now)
+        with self._phase("emit"):
+            self._decode_account(active_slots, active, nxt, t0, wall)
+            for s in active_slots:
+                req = self.sched.running[s]
+                self._slot_len[s] += 1
+                self.stats.decode_tokens += 1
+                self._emit(req, host[s, ..., 0], now)
+                if req.done:
+                    self._complete(req, now)
+
+    def _decode_account(self, active_slots: List[int], active: np.ndarray,
+                        nxt, t0: float, wall: float,
+                        guarded: bool = False) -> None:
+        """Stats, metrics, trace span and device token state of one
+        ``[slots, 1]`` decode dispatch (plain or guarded)."""
         self.stats.decode_time += wall
         self.metrics.observe("dispatch_decode_s", wall)
-        if trace:
+        if self.tracer.enabled:
             rows = len(active_slots)
-            self.tracer.span(
-                "decode", "dispatch", "dispatch", t0, wall,
-                args={"kind": "decode", "h": 1, "spec_k": 0,
-                      "slots_active": rows, "tokens": rows, "rows": rows,
-                      "host_syncs": 1,
-                      "odin_energy_mj": self.cost_model.energy_mj(rows)})
+            args = {"kind": "decode", "h": 1, "spec_k": 0,
+                    "slots_active": rows, "tokens": rows, "rows": rows,
+                    "host_syncs": 1,
+                    "odin_energy_mj": self.cost_model.energy_mj(rows)}
+            if guarded:
+                args["guarded"] = True
+            self.tracer.span("decode", "dispatch", "dispatch", t0, wall,
+                             args=args)
         self.stats.decode_steps += 1
         self.stats.dispatches += 1
         self.stats.decode_dispatches += 1
@@ -1458,15 +1551,6 @@ class ServingEngine:
             shifted = jnp.concatenate([self._hist[:, 1:], nxt], axis=1)
             self._hist = jnp.where(jnp.asarray(active)[:, None], shifted,
                                    self._hist)
-        now = self._now()
-        for s in active_slots:
-            req = self.sched.running[s]
-            self._record_writes(req, int(self._slot_len[s]), 1, now)
-            self._slot_len[s] += 1
-            self.stats.decode_tokens += 1
-            self._emit(req, host[s, ..., 0], now)
-            if req.done:
-                self._complete(req, now)
 
     def _guarded_fn(self):
         """Lazily-compiled guarded decode step: same math as the plain step
@@ -1487,64 +1571,53 @@ class ServingEngine:
         FAILED; every other slot samples from untouched logits with the
         same key schedule as the plain step, so unfaulted co-batched greedy
         streams stay bit-identical to a fault-free run."""
-        trace = self.tracer.enabled
         t0 = self._now()            # engine clock: metrics ≡ stats ≡ trace
-        active = np.zeros(self.slots, bool)
-        active[active_slots] = True
-        poison = np.zeros(self.slots, bool)
-        target = active_slots[ev.slot % len(active_slots)]
-        poison[target] = True
-        self.fault_plan.record(ev, "poisoned", slot=target,
-                               rid=self.sched.running[target].rid)
-        tables = self._refresh_tables()
-        key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-        with self._annotate("decode"):
+        with self._phase("pack"):
+            active = np.zeros(self.slots, bool)
+            active[active_slots] = True
+            poison = np.zeros(self.slots, bool)
+            target = active_slots[ev.slot % len(active_slots)]
+            poison[target] = True
+            self.fault_plan.record(ev, "poisoned", slot=target,
+                                   rid=self.sched.running[target].rid)
+        with self._phase("tables"):
+            tables = self._refresh_tables()
+        with self._phase("decode"):
+            key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
             nxt, bad, self.caches = self._guarded_fn()(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 tables, key, jnp.float32(self.temperature),
                 jnp.asarray(poison))
+        with self._phase("sync"):
             host = np.asarray(nxt)                   # syncs the step
             badh = np.asarray(bad)
         wall = self._now() - t0
-        self.stats.decode_time += wall
-        self.metrics.observe("dispatch_decode_s", wall)
-        if trace:
-            rows = len(active_slots)
-            self.tracer.span(
-                "decode", "dispatch", "dispatch", t0, wall,
-                args={"kind": "decode", "h": 1, "spec_k": 0, "guarded": True,
-                      "slots_active": rows, "tokens": rows, "rows": rows,
-                      "host_syncs": 1,
-                      "odin_energy_mj": self.cost_model.energy_mj(rows)})
-        self.stats.decode_steps += 1
-        self.stats.dispatches += 1
-        self.stats.decode_dispatches += 1
-        self.stats.host_syncs += 1
-        self.stats.active_slot_steps += len(active_slots)
-        self.stats.slot_steps += self.slots
-        self._last_tok = nxt
-        if self.spec_ngram:
-            shifted = jnp.concatenate([self._hist[:, 1:], nxt], axis=1)
-            self._hist = jnp.where(jnp.asarray(active)[:, None], shifted,
-                                   self._hist)
         now = self._now()
-        for s in active_slots:
-            req = self.sched.running[s]
-            # the forward wrote this slot's KV row whether or not the logit
+        with self._phase("wear"):
+            # the forward wrote each slot's KV row whether or not the logit
             # readout was poisoned — wear is physical, bill it either way
-            self._record_writes(req, int(self._slot_len[s]), 1, now)
-            if badh[s]:
-                # quarantine: only the poisoned request fails; its garbage
-                # token never enters a stream and the slot is re-admittable
-                self.stats.nan_quarantined += 1
-                self._finalize(req, RequestState.FAILED, "nan_logits", now)
-                continue
-            self._slot_len[s] += 1
-            self.stats.decode_tokens += 1
-            self._emit(req, host[s, ..., 0], now)
-            if req.done:
-                self._complete(req, now)
+            for s in active_slots:
+                self._record_writes(self.sched.running[s],
+                                    int(self._slot_len[s]), 1, now)
+        with self._phase("emit"):
+            self._decode_account(active_slots, active, nxt, t0, wall,
+                                 guarded=True)
+            for s in active_slots:
+                req = self.sched.running[s]
+                if badh[s]:
+                    # quarantine: only the poisoned request fails; its
+                    # garbage token never enters a stream and the slot is
+                    # re-admittable
+                    self.stats.nan_quarantined += 1
+                    self._finalize(req, RequestState.FAILED, "nan_logits",
+                                   now)
+                    continue
+                self._slot_len[s] += 1
+                self.stats.decode_tokens += 1
+                self._emit(req, host[s, ..., 0], now)
+                if req.done:
+                    self._complete(req, now)
 
     def _decode_horizon_steps(self, active_slots: List[int], h: int) -> None:
         """One fused dispatch generating up to ``h`` tokens per slot.
@@ -1557,13 +1630,10 @@ class ServingEngine:
         engine clock* (the host cannot observe inner-step boundaries — that
         is the point; an injected test clock stays self-consistent)."""
         t_before = self._now()      # engine clock: metrics ≡ stats ≡ trace
-        active = np.zeros(self.slots, bool)
-        active[active_slots] = True
-        rem = np.zeros(self.slots, np.int32)
-        for s in active_slots:
-            rem[s] = self.sched.running[s].remaining
-        tables = self._refresh_tables()
-        with self._annotate("horizon"):
+        active, rem = self._pack_fused(active_slots)
+        with self._phase("tables"):
+            tables = self._refresh_tables()
+        with self._phase("horizon"):
             block, counts, last, self.caches = self._horizon_fn(h)(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
@@ -1571,43 +1641,59 @@ class ServingEngine:
                 jnp.float32(self.temperature),
                 jnp.int32(self.stats.decode_steps),
                 jnp.int32(-1 if self.eos_id is None else self.eos_id))
-            block, counts = jax.device_get((block, counts))  # ONE sync for h steps
+        with self._phase("sync"):
+            block, counts = jax.device_get((block, counts))  # ONE sync, h steps
         wall = self._now() - t_before
-        self.stats.decode_time += wall
-        self.metrics.observe("dispatch_decode_s", wall)
-        if self.tracer.enabled:
-            emitted = int(counts.sum())
-            self.tracer.span(
-                "horizon", "dispatch", "dispatch", t_before, wall,
-                args={"kind": "horizon", "h": h, "spec_k": 0,
-                      "slots_active": len(active_slots), "tokens": emitted,
-                      "rows": emitted, "host_syncs": 1,
-                      "odin_energy_mj": self.cost_model.energy_mj(emitted)})
-        self.stats.decode_steps += h
-        self.stats.dispatches += 1
-        self.stats.decode_dispatches += 1
-        self.stats.host_syncs += 1
-        self.stats.active_slot_steps += int(counts.sum())
-        self.stats.slot_steps += self.slots * h
-        self._last_tok = last
         now_w = self._now()
-        for s in active_slots:
-            # endurance mirror: the scan wrote counts[s] KV rows for this
-            # slot starting at its pre-dispatch length
-            self._record_writes(self.sched.running[s],
-                                int(self._slot_len[s]), int(counts[s]), now_w)
-        span = wall                              # engine-clock dispatch span
-        for hh in range(h):                      # step-major: matches h=1 order
-            t_h = t_before + (hh + 1) * span / h
+        with self._phase("wear"):
             for s in active_slots:
-                if hh < counts[s]:
-                    self._slot_len[s] += 1
-                    self.stats.decode_tokens += 1
-                    self._emit(self.sched.running[s], block[s, ..., hh], t_h)
-        for s in active_slots:
-            req = self.sched.running[s]
-            if req.done:
-                self._complete(req, t_before + int(counts[s]) * span / h)
+                # endurance mirror: the scan wrote counts[s] KV rows for
+                # this slot starting at its pre-dispatch length
+                self._record_writes(self.sched.running[s],
+                                    int(self._slot_len[s]), int(counts[s]),
+                                    now_w)
+        with self._phase("emit"):
+            self.stats.decode_time += wall
+            self.metrics.observe("dispatch_decode_s", wall)
+            emitted = int(counts.sum())
+            if self.tracer.enabled:
+                self.tracer.span(
+                    "horizon", "dispatch", "dispatch", t_before, wall,
+                    args={"kind": "horizon", "h": h, "spec_k": 0,
+                          "slots_active": len(active_slots),
+                          "tokens": emitted, "rows": emitted, "host_syncs": 1,
+                          "odin_energy_mj": self.cost_model.energy_mj(emitted)})
+            self.stats.decode_steps += h
+            self.stats.dispatches += 1
+            self.stats.decode_dispatches += 1
+            self.stats.host_syncs += 1
+            self.stats.active_slot_steps += emitted
+            self.stats.slot_steps += self.slots * h
+            self._last_tok = last
+            span = wall                          # engine-clock dispatch span
+            for hh in range(h):                  # step-major: matches h=1 order
+                t_h = t_before + (hh + 1) * span / h
+                for s in active_slots:
+                    if hh < counts[s]:
+                        self._slot_len[s] += 1
+                        self.stats.decode_tokens += 1
+                        self._emit(self.sched.running[s], block[s, ..., hh],
+                                   t_h)
+            for s in active_slots:
+                req = self.sched.running[s]
+                if req.done:
+                    self._complete(req, t_before + int(counts[s]) * span / h)
+
+    def _pack_fused(self, active_slots: List[int]):
+        """The ``pack`` phase of a fused decode: active mask and each slot's
+        remaining token budget."""
+        with self._phase("pack"):
+            active = np.zeros(self.slots, bool)
+            active[active_slots] = True
+            rem = np.zeros(self.slots, np.int32)
+            for s in active_slots:
+                rem[s] = self.sched.running[s].remaining
+        return active, rem
 
     def _decode_spec_steps(self, active_slots: List[int], h: int) -> None:
         """One fused dispatch of ``h`` draft→verify→accept inner steps.
@@ -1619,81 +1705,83 @@ class ServingEngine:
         step across its accepted run."""
         K = self.spec_ngram
         t_before = self._now()      # engine clock: metrics ≡ stats ≡ trace
-        active = np.zeros(self.slots, bool)
-        active[active_slots] = True
-        rem = np.zeros(self.slots, np.int32)
-        for s in active_slots:
-            rem[s] = self.sched.running[s].remaining
-        tables = self._refresh_tables()
-        with self._annotate("spec-horizon"):
+        active, rem = self._pack_fused(active_slots)
+        with self._phase("tables"):
+            tables = self._refresh_tables()
+        with self._phase("spec-horizon"):
             block, counts, last, hist, self.caches = self._fused_fn(h, K)(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 jnp.asarray(rem), self._hist, tables,
                 jnp.int32(-1 if self.eos_id is None else self.eos_id))
+        with self._phase("sync"):
             block, counts = jax.device_get((block, counts))   # ONE sync
-        self._last_tok = last
-        self._hist = hist
         wall = self._now() - t_before
-        self.stats.decode_time += wall
-        self.metrics.observe("dispatch_decode_s", wall)
-        self.stats.decode_steps += h
-        self.stats.dispatches += 1
-        self.stats.decode_dispatches += 1
-        self.stats.host_syncs += 1
-        live = counts > 0                                  # [slots, h]
-        self.stats.active_slot_steps += int(live.sum())
-        self.stats.slot_steps += self.slots * h
-        self.stats.spec_drafted += K * int(live.sum())
-        self.stats.spec_accepted += int((counts - live).sum())
-        # every live inner step verified a K+1-row forward; rows beyond the
-        # emitted run are rejected drafts — real PIMC energy, billed as
-        # verify overhead (satellite 2: spec_overhead_rows) both fleet-wide
-        # and on the request that incurred them
-        emitted = int(counts.sum())
-        rows = (K + 1) * int(live.sum())
-        self.stats.spec_overhead_rows += rows - emitted
-        for s in active_slots:
-            s_over = int(((K + 1) * live[s] - counts[s]).sum())
-            if s_over:
-                self.sched.running[s].spec_overhead_rows += s_over
-        if self.tracer.enabled:
-            self.tracer.span(
-                "spec-horizon", "dispatch", "dispatch", t_before, wall,
-                args={"kind": "spec-horizon", "h": h, "spec_k": K,
-                      "slots_active": len(active_slots), "tokens": emitted,
-                      "drafted": K * int(live.sum()),
-                      "accepted": int((counts - live).sum()),
-                      "rows": rows, "overhead_rows": rows - emitted,
-                      "host_syncs": 1,
-                      "odin_energy_mj": self.cost_model.energy_mj(rows)})
         now_w = self._now()
-        for s in active_slots:
-            # endurance mirror: every live inner step wrote a K+1-row verify
-            # tile at the slot's running position (rejected rows were
-            # physically written before rollback — their wear is real), and
-            # the position advanced by the accepted count
-            pos = int(self._slot_len[s])
-            for hh in range(h):
-                if live[s, hh]:
-                    self._record_writes(self.sched.running[s], pos, K + 1,
-                                        now_w)
-                    pos += int(counts[s, hh])
-        span = wall
-        last_t = {}
-        for hh in range(h):                      # step-major: matches h=1 order
+        live = counts > 0                                  # [slots, h]
+        with self._phase("wear"):
             for s in active_slots:
-                m = int(counts[s, hh])
-                for j in range(m):
-                    t_tok = t_before + (hh + (j + 1) / m) * span / h
-                    self._slot_len[s] += 1
-                    self.stats.decode_tokens += 1
-                    self._emit(self.sched.running[s], block[s, hh, j], t_tok)
-                    last_t[s] = t_tok
-        for s in active_slots:
-            req = self.sched.running[s]
-            if req.done:
-                self._complete(req, last_t.get(s, t_before + span))
+                # endurance mirror: every live inner step wrote a K+1-row
+                # verify tile at the slot's running position (rejected rows
+                # were physically written before rollback — their wear is
+                # real), and the position advanced by the accepted count
+                pos = int(self._slot_len[s])
+                for hh in range(h):
+                    if live[s, hh]:
+                        self._record_writes(self.sched.running[s], pos,
+                                            K + 1, now_w)
+                        pos += int(counts[s, hh])
+        with self._phase("emit"):
+            self._last_tok = last
+            self._hist = hist
+            self.stats.decode_time += wall
+            self.metrics.observe("dispatch_decode_s", wall)
+            self.stats.decode_steps += h
+            self.stats.dispatches += 1
+            self.stats.decode_dispatches += 1
+            self.stats.host_syncs += 1
+            self.stats.active_slot_steps += int(live.sum())
+            self.stats.slot_steps += self.slots * h
+            self.stats.spec_drafted += K * int(live.sum())
+            self.stats.spec_accepted += int((counts - live).sum())
+            # every live inner step verified a K+1-row forward; rows beyond
+            # the emitted run are rejected drafts — real PIMC energy, billed
+            # as verify overhead both fleet-wide and on the request that
+            # incurred them
+            emitted = int(counts.sum())
+            rows = (K + 1) * int(live.sum())
+            self.stats.spec_overhead_rows += rows - emitted
+            for s in active_slots:
+                s_over = int(((K + 1) * live[s] - counts[s]).sum())
+                if s_over:
+                    self.sched.running[s].spec_overhead_rows += s_over
+            if self.tracer.enabled:
+                self.tracer.span(
+                    "spec-horizon", "dispatch", "dispatch", t_before, wall,
+                    args={"kind": "spec-horizon", "h": h, "spec_k": K,
+                          "slots_active": len(active_slots),
+                          "tokens": emitted,
+                          "drafted": K * int(live.sum()),
+                          "accepted": int((counts - live).sum()),
+                          "rows": rows, "overhead_rows": rows - emitted,
+                          "host_syncs": 1,
+                          "odin_energy_mj": self.cost_model.energy_mj(rows)})
+            span = wall
+            last_t = {}
+            for hh in range(h):                  # step-major: matches h=1 order
+                for s in active_slots:
+                    m = int(counts[s, hh])
+                    for j in range(m):
+                        t_tok = t_before + (hh + (j + 1) / m) * span / h
+                        self._slot_len[s] += 1
+                        self.stats.decode_tokens += 1
+                        self._emit(self.sched.running[s], block[s, hh, j],
+                                   t_tok)
+                        last_t[s] = t_tok
+            for s in active_slots:
+                req = self.sched.running[s]
+                if req.done:
+                    self._complete(req, last_t.get(s, t_before + span))
 
     def _horizon_fn(self, h: int) -> Callable:
         return self._fused_fn(h, 0)

@@ -298,9 +298,14 @@ class FrontDoor:
 
     async def _consume(self, h: _Stream) -> AsyncIterator:
         req = h.req
+        eng = self.engine
         try:
             while True:
                 ev = await h.queue.get()
+                if ev.kind == "token":
+                    # delivery lag: emission stamp → the consumer, one clock
+                    eng.stats.deliver_lag_s += eng._now() - ev.t
+                    eng.stats.delivered_tokens += 1
                 yield ev
                 if ev.kind == "done":
                     return
@@ -321,35 +326,40 @@ class FrontDoor:
             self._driver = asyncio.ensure_future(self._drive())
 
     async def _drive(self) -> None:
+        """The driver loop.  Its host phases tile it with the engine's:
+        ``deliver`` (terminal-event routing, heartbeats, and the yield in
+        which consumers and clients run) and ``idle`` (waiting for work)."""
         eng = self.engine
         try:
             while not self._closed:
                 if eng.sched.has_work:
                     self._step()
-                    self._heartbeats()
-                    # yield so consumers drain their queues between steps
-                    await asyncio.sleep(0)
+                    with eng._phase("deliver"):
+                        self._route_done()
+                        self._heartbeats()
+                        # yield so consumers drain their queues between steps
+                        await asyncio.sleep(0)
                 else:
-                    self._route_done()
+                    with eng._phase("deliver"):
+                        self._route_done()
                     self._wake.clear()
                     timeout = self.heartbeat_s if self.heartbeat_s else None
                     try:
-                        await asyncio.wait_for(self._wake.wait(), timeout)
+                        with eng._phase("idle"):
+                            await asyncio.wait_for(self._wake.wait(), timeout)
                     except asyncio.TimeoutError:
                         self._heartbeats(force_idle=True)
         except asyncio.CancelledError:
             pass
 
     def _step(self) -> None:
-        """One engine step plus terminal-event routing.  If the step raises,
-        every open stream gets a ``failed`` terminal event before the
-        exception propagates."""
+        """One engine step.  If it raises, every open stream gets a
+        ``failed`` terminal event before the exception propagates."""
         try:
             self.engine.step()
         except Exception as exc:
             self._fail_streams(exc)
             raise
-        self._route_done()
 
     def _fail_streams(self, exc: BaseException) -> None:
         self._failure = exc
@@ -413,7 +423,9 @@ class FrontDoor:
                 await asyncio.sleep(0)
                 while eng.sched.has_work and self._failure is None:
                     self._step()
-                    await asyncio.sleep(0)
+                    with eng._phase("deliver"):
+                        self._route_done()
+                        await asyncio.sleep(0)
                 self._route_done()
             # let consumers drain their final events before the driver stops
             for _ in range(3):
@@ -424,7 +436,8 @@ class FrontDoor:
             raise self._failure
 
     async def aclose(self) -> None:
-        """Detach from the engine: stop the driver and restore the hooks.
+        """Detach from the engine: stop the driver, restore the hooks, and
+        remove the tracer's GC hook.
 
         Unlike :meth:`shutdown` this does not drain — the engine stays
         serviceable for direct (synchronous) use afterwards."""
@@ -443,6 +456,7 @@ class FrontDoor:
             self._driver = None
         self.engine.on_token = self._prev_on_token
         self.engine.sched.victim_key = self._prev_victim_key
+        self.engine.tracer.detach()          # the GC hook goes with it
 
     def summary(self) -> Dict:
         out = dict(self.stats)

@@ -71,6 +71,23 @@ class EngineStats:
     scrub_rows: int = 0                   # cache rows those rewrites moved
     wear_p99: float = 0.0                 # p99 of the per-block wear counters
     wear_max: int = 0                     # most-worn block's write count
+    # host phases of the loop (engine clock, always on; each phase's own
+    # seconds, nested phases and counted GC pauses taken out)
+    host_plan_s: float = 0.0              # expiry … Scheduler.plan … admission
+    host_pack_s: float = 0.0              # pack_mixed + the host input tile
+    host_tables_s: float = 0.0            # block-table mirror and upload
+    dispatch_launch_s: float = 0.0        # compiled call: enqueue + arguments
+    dispatch_sync_s: float = 0.0          # waiting for the result on the host
+    host_wear_s: float = 0.0              # endurance mirror (_record_writes)
+    host_emit_s: float = 0.0              # emission, completion, bookkeeping
+    deliver_s: float = 0.0                # front door: routing + the yield
+    idle_wait_s: float = 0.0              # front door: waiting with no work
+    # garbage collection, every generation, while a Tracer is attached
+    gc_pause_s: float = 0.0
+    gc_collections: int = 0
+    # front door: engine-clock emission → the consumer's receipt, per token
+    deliver_lag_s: float = 0.0
+    delivered_tokens: int = 0
 
     @property
     def occupancy(self) -> float:

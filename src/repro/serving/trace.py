@@ -18,6 +18,17 @@ Two instruments, both cheap enough to leave compiled-in:
   no-op recorder whose ``enabled`` flag lets every call site skip even the
   argument-dict construction, so the trace-off hot path allocates nothing.
 
+  **Host phases** (``with tracer.phase("plan"):``) tile the engine's step
+  loop and the front door's driver: each records an ``X`` span named
+  ``serving/<phase>`` under category ``host`` on the ``host`` track and,
+  when the engine asks for profiler annotations, opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the span also lands
+  on the profiler's clock beside the device's ops.  Phases never overlap: a
+  phase opened while another is open pauses it until it closes, and a
+  generation-2 garbage collection pauses whichever phase is open for a
+  ``serving/gc`` span (a ``gc.callbacks`` hook, installed while the tracer
+  is attached to an engine).
+
 * :class:`MetricsRegistry` — windowed serving metrics.  Log-bucketed
   streaming histograms (TTFT / TPOT / per-dispatch wall time) plus counter
   deltas are snapshotted every ``window_s`` seconds of engine clock, so a
@@ -41,8 +52,10 @@ Usage::
 """
 from __future__ import annotations
 
+import gc
 import json
 import math
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -79,6 +92,21 @@ class TraceEvent:
         self.flow = flow
 
 
+class _NullPhase:
+    """The trace-off phase: one shared object, nothing built or recorded."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_PHASE = _NullPhase()
+
+
 class NullTracer:
     """No-op recorder — the trace-off default.
 
@@ -97,6 +125,16 @@ class NullTracer:
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         pass
+
+    def attach(self, clock: Callable[[], float], stats=None,
+               annotate: bool = False) -> None:
+        pass
+
+    def detach(self) -> None:
+        pass
+
+    def phase(self, name: str) -> _NullPhase:
+        return _NULL_PHASE
 
     def span(self, name, cat, track, ts, dur, args=None, flow=None) -> None:
         pass
@@ -138,10 +176,65 @@ class Tracer(NullTracer):
         self.dropped_events = 0
         self._clock: Callable[[], float] = lambda: 0.0
         self._tracks: Dict[str, int] = {}
+        self._annotation = None            # TraceAnnotation class, or None
+        self._open: List["_Phase"] = []    # open phases, innermost last
+        self._switching = False            # a phase is being opened/closed
+        self._stats = None                 # where GC pauses are counted
+        self._gc_t0 = 0.0
+        self._gc_phase: Optional[_Phase] = None
+        self._unhook: Optional[weakref.finalize] = None
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         """Attach the timestamp source (the engine's run clock, seconds)."""
         self._clock = clock
+
+    def attach(self, clock: Callable[[], float], stats=None,
+               annotate: bool = False) -> None:
+        """Attach to an engine: its clock, the stats object whose
+        ``gc_pause_s`` / ``gc_collections`` count garbage collections (every
+        generation), and whether phases open profiler annotations.  Installs
+        the ``gc.callbacks`` hook until :meth:`detach`; the hook holds the
+        tracer weakly and a dropped tracer takes its hook with it."""
+        self.set_clock(clock)
+        self._stats = stats
+        if annotate:
+            import jax.profiler
+            self._annotation = jax.profiler.TraceAnnotation
+        else:
+            self._annotation = None
+        if self._unhook is None:
+            hook = _gc_hook(weakref.ref(self))
+            gc.callbacks.append(hook)
+            self._unhook = weakref.finalize(self, _remove_hook, hook)
+
+    def detach(self) -> None:
+        """Remove the GC hook (idempotent); spans still record."""
+        if self._unhook is not None:
+            self._unhook()
+            self._unhook = None
+
+    def phase(self, name: str) -> "_Phase":
+        """Context manager for one host phase: an ``X`` span
+        ``serving/<name>`` (category and track ``host``, no args) and, when
+        annotating, the profiler annotation of the same name.  An enclosing
+        open phase is paused for its duration."""
+        return _Phase(self, "serving/" + name)
+
+    def _on_gc(self, when: str, info: dict) -> None:
+        if when == "start":
+            self._gc_t0 = self._clock()
+            # a collection that lands while a phase is switching stays in
+            # that phase's span; the counters below still take it
+            if info.get("generation") == 2 and not self._switching:
+                self._gc_phase = self.phase("gc")
+                self._gc_phase.__enter__()
+            return
+        if self._gc_phase is not None:
+            self._gc_phase.__exit__(None, None, None)
+            self._gc_phase = None
+        if self._stats is not None:
+            self._stats.gc_pause_s += self._clock() - self._gc_t0
+            self._stats.gc_collections += 1
 
     # -- recording ----------------------------------------------------------
 
@@ -201,6 +294,71 @@ class Tracer(NullTracer):
         with open(path, "w") as f:
             json.dump(obj, f, allow_nan=False)
         return obj
+
+
+class _Phase:
+    """One open host phase of a :class:`Tracer` (see :meth:`Tracer.phase`).
+
+    A phase runs in segments: opening an inner phase (or a GC pause) ends
+    the outer one's segment, closing it starts a new one, so the recorded
+    spans and annotations never overlap."""
+
+    __slots__ = ("tracer", "name", "t0", "ann")
+
+    def __init__(self, tracer: Tracer, name: str):
+        self.tracer = tracer
+        self.name = name
+        self.ann = None
+
+    def __enter__(self):
+        tr = self.tracer
+        tr._switching = True
+        now = tr._clock()
+        if tr._open:
+            tr._open[-1]._stop(now)
+        tr._open.append(self)
+        self._start(now)
+        tr._switching = False
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracer
+        tr._switching = True
+        if tr._open and tr._open[-1] is self:
+            now = tr._clock()
+            self._stop(now)
+            tr._open.pop()
+            if tr._open:
+                tr._open[-1]._start(now)
+        elif self in tr._open:              # closed out of order: its
+            tr._open.remove(self)           # last segment is recorded
+        tr._switching = False
+
+    def _start(self, now: float) -> None:
+        self.t0 = now
+        if self.tracer._annotation is not None:
+            self.ann = self.tracer._annotation(self.name)
+            self.ann.__enter__()
+
+    def _stop(self, now: float) -> None:
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        self.tracer._push(TraceEvent(self.name, "host", "X", "host",
+                                     self.t0, now - self.t0))
+
+
+def _gc_hook(ref: "weakref.ref"):
+    def hook(when: str, info: dict) -> None:
+        tracer = ref()
+        if tracer is not None:
+            tracer._on_gc(when, info)
+    return hook
+
+
+def _remove_hook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
 
 
 # ------------------------------------------------------------ chrome export

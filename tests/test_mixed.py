@@ -208,6 +208,7 @@ def test_engine_dispatch_walls_single_clock_domain():
     eng = ServingEngine(cfg, slots=3, max_len=48, block_size=8, params=params,
                         tracer=tracer, clock=_Clock())
     eng.run(make_requests(cfg, _staggered(), seed=9))
+    tracer.detach()                  # no GC hook outlives the test
     st = eng.stats
     assert st.mixed_dispatches > 0
     ledger = st.prefill_time + st.decode_time

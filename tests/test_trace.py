@@ -251,6 +251,7 @@ def _traced_run(**kw):
                         clock=_Clock(), tracer=tracer, **kw)
     reqs = make_requests(cfg, mixed_spec(), seed=9)
     summary = eng.run(reqs)
+    tracer.detach()                  # no GC hook outlives the test
     return tracer, summary, eng
 
 
@@ -336,10 +337,17 @@ def test_engine_trace_scheduler_and_pool_decisions():
 
 
 class _SpyTracer(NullTracer):
-    """enabled=False recorder that counts any emit that still happens."""
+    """enabled=False recorder that counts any emit that still happens, and
+    every distinct object its phases hand out."""
 
     def __init__(self):
         self.calls = 0
+        self.phases = set()
+
+    def phase(self, name):
+        ph = NullTracer.phase(self, name)
+        self.phases.add(id(ph))
+        return ph
 
     def span(self, *a, **kw):
         self.calls += 1
@@ -354,18 +362,33 @@ class _SpyTracer(NullTracer):
         self.calls += 1
 
 
-def test_engine_trace_off_emits_nothing():
+def test_engine_trace_off_emits_nothing(monkeypatch):
     """The no-op path must not merely record nothing — it must never be
     called: every emit site guards on tracer.enabled, so trace-off skips
-    even the argument-dict construction."""
+    even the argument-dict construction.  Host phases get the one shared
+    no-op object, build no profiler annotation (even with
+    ``xla_annotations``) and install no GC hook."""
+    import gc
+
+    import jax
+    annotations = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: annotations.append(name))
+    gc.collect()                     # unreachable tracers take their hooks
+    n_hooks = len(gc.callbacks)
     cfg, params = materialize("phi4-mini-3.8b")
     eng = ServingEngine(cfg, slots=3, max_len=48, block_size=8, params=params)
     assert eng.tracer is NULL_TRACER                  # off by default
     spy = _SpyTracer()
     eng = ServingEngine(cfg, slots=3, max_len=48, block_size=8, params=params,
-                        n_blocks=8, swap_blocks=32, horizon=4, tracer=spy)
+                        n_blocks=8, swap_blocks=32, horizon=4, tracer=spy,
+                        xla_annotations=True)
     eng.run(make_requests(cfg, mixed_spec(), seed=9))
     assert spy.calls == 0
+    assert spy.phases == {id(NULL_TRACER.phase("plan"))}
+    assert annotations == []
+    assert len(gc.callbacks) == n_hooks
+    assert eng.stats.host_plan_s > 0 and eng.stats.gc_collections == 0
 
 
 def test_engine_stats_fields_all_reported_in_summary():
@@ -428,12 +451,16 @@ def test_engine_metrics_windows_and_histograms():
 
 
 def test_xla_annotations_smoke():
-    """xla_annotations=True must run end-to-end (TraceAnnotation wraps every
-    dispatch) without changing tokens."""
+    """xla_annotations=True with a tracer must run end-to-end (a
+    TraceAnnotation around every host phase) without changing tokens."""
     cfg, params = materialize("phi4-mini-3.8b")
     base, _ = run_workload(cfg, params, horizon=4)
-    notes, _ = run_workload(cfg, params, horizon=4, xla_annotations=True)
+    tracer = Tracer()
+    notes, _ = run_workload(cfg, params, horizon=4, xla_annotations=True,
+                            tracer=tracer)
+    tracer.detach()
     assert base == notes
+    assert any(ev.cat == "host" for ev in tracer.events())
 
 
 # ---------------------------------------------------------------------------
