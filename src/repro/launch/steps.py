@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import BlockConfig, ModelConfig
 from repro.models import lm
-from repro.nn.attention import POOL_LEAVES, init_paged_cache
+from repro.nn.attention import POOL_LEAVES, MixedRows, init_paged_cache
 from repro.nn.module import ParamSpec
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.compress import compressed_psum
@@ -380,60 +380,54 @@ def make_serving_mixed_step(cfg: ModelConfig, top_k: int = 0,
                             sample: bool = False) -> Callable:
     """ONE dispatch carrying decode rows AND prefill-chunk rows together.
 
-    (params, caches, tokens [B,Q] (or [B,K,Q]), lengths [B], q_lens [B],
-     decode [B], active [B], tables [B,P], key, temperature)
+    (params, caches, dec_tokens [B] (or [B,K]), lane_tokens [L,Q] (or
+     [L,K,Q]), lengths [B], decode [B], lane_slot [L], lane_lens [L],
+     tables [B,P], key, temperature)
         → (next_tokens, last_logits [B,V] (or [B,K,V]), caches)
 
-    The mixed tile: every slot contributes ``q_lens[s]`` real query rows,
-    right-aligned in the fixed ``Q`` columns — a decode slot rides at
-    ``q_lens = 1`` (its pending token in column Q-1, flagged in ``decode``),
-    a prefilling slot carries a chunk of its prompt at ``q_lens = c ≤ Q``.
-    Because tiles are right-aligned, ``logits[:, -1]`` is the last real
-    token's logits for every slot, so the same :func:`_sample_tokens` serves
-    both populations: for decode slots it is the next emitted token, for a
-    slot that just finished its prompt it is the first generated token, and
-    for a mid-prompt slot it is discarded by the engine.  ``lengths`` is the
-    per-slot cached length *before* this dispatch (== cache ``pos``).
-    Bit-identity with the separate paths is structural, not approximate:
-    prefill rows run the chunked-prefill gather+sdpa core and decode rows
-    run the decode kernel (``q_decode`` selection in the attention layer),
-    so each emitted token is the argmax/sample over *the same floats* the
-    separate prefill/decode dispatches would have produced.
+    The program runs only the rows the dispatch carries, in two groups
+    (``nn.attention.MixedRows``): a **decode group** of one row per slot —
+    slot ``b``'s pending token where ``decode[b]``, a pad row elsewhere —
+    and ``L`` **prefill lanes** of ``Q`` rows, lane ``l`` carrying
+    ``lane_lens[l]`` replay tokens of slot ``lane_slot[l]``, right-aligned.
+    Embedding, norms, projections and MLP run on the flat ``B + L·Q`` rows,
+    so the weights stream once; attention splits the groups, and the LM
+    head runs only on the ``B + L`` rows whose logits are read (every
+    decode row and each lane's last row).  Programs are keyed by ``Q``
+    alone: ``B`` and ``L`` are fixed per engine.
 
-    Inactive slots run with ``q_lens = 0``: every row of theirs is a pad row
-    whose K/V writes land in the pool's write-off block (their tables are
-    additionally redirected there), and their ``pos`` does not advance.
+    ``last_logits[s]`` is slot ``s``'s last real row: the next emitted
+    token for a decode slot, the first generated token for a slot whose
+    prompt just finished (mid-prompt slots' are discarded by the engine),
+    and ``next_tokens`` samples it with :func:`_sample_tokens`.
+    ``lengths`` is the per-slot cached length *before* this dispatch (==
+    cache ``pos``), which advances by each slot's real rows.  Equality with
+    the separate paths is structural, not approximate: the decode group
+    makes the decode program's kernel call and each lane the chunked
+    prefill's gather+sdpa call, so each emitted token is the argmax/sample
+    over *the same floats* the separate prefill/decode dispatches would
+    have produced.  Pad rows write to the pool's write-off block.
     ``last_logits`` rides back to the host so the engine can emit first
     tokens of finishing prefills with the same host-side argmax/sampling it
-    uses on the separate path (bit-identical first tokens).
+    uses on the separate path.
     """
 
-    def mixed_step(params, caches, tokens, lengths, q_lens, decode, active,
-                   tables=None, key=None, temperature=0.0):
-        trash = _pool_trash_block(caches)
-        Q = tokens.shape[-1]
-        q_lens = jnp.where(active, q_lens, 0)
-        tabs = tables
-        if tabs is not None and trash is not None:
-            tabs = jnp.where(active[:, None], tabs, jnp.int32(trash))
-        # row 0 of the tile sits q_lens-Q rows *before* the slot's next
-        # position (pad rows get earlier/negative positions; discarded)
-        start = (lengths + q_lens - Q)[:, None]
-        logits, new_caches, _ = lm.forward(params, tokens, cfg, caches=caches,
-                                           start_pos=start, moe_no_drop=True,
-                                           tables=tabs, q_lens=q_lens,
-                                           q_decode=decode & active)
-
-        def merge(path, old, new):
-            if _leaf_name(path) in POOL_LEAVES:
-                return new          # pad/inactive writes went to the trash block
-            m = active.reshape((1, active.shape[0]) + (1,) * (old.ndim - 2))
-            return jnp.where(m, new, old)
-
-        caches = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
-        nxt = _sample_tokens(logits, cfg, key if sample else None,
+    def mixed_step(params, caches, dec_tokens, lane_tokens, lengths, decode,
+                   lane_slot, lane_lens, tables, key=None, temperature=0.0):
+        rows = MixedRows(decode, lane_slot, lane_lens)
+        B = decode.shape[0]
+        tokens = MixedRows.flat_tokens(dec_tokens, lane_tokens)
+        logits, caches, _ = lm.forward(params, tokens, cfg, caches=caches,
+                                       start_pos=lengths, moe_no_drop=True,
+                                       tables=tables, mixed=rows)
+        logits = logits[0]                      # [B + L, V] or [B + L, K, V]
+        # every slot's last real row: its decode row, or its lane's last row
+        # (empty lanes are dropped)
+        dst = jnp.where(lane_lens > 0, lane_slot, B)
+        last = logits[:B].at[dst].set(logits[B:], mode="drop")
+        nxt = _sample_tokens(last[:, None], cfg, key if sample else None,
                              temperature, top_k)
-        return nxt, logits[:, -1], caches
+        return nxt, last, caches
 
     return mixed_step
 

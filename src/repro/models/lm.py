@@ -72,8 +72,7 @@ def param_spec(cfg: ModelConfig) -> Dict:
 
 def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3d,
                    odin, remat: str, norm_eps: float, moe_no_drop: bool = False,
-                   tables=None, spec_decode: bool = False, q_lens=None,
-                   q_decode=None):
+                   tables=None, spec_decode: bool = False, mixed=None):
     """Scan one homogeneous segment of layers over the sequence activations."""
     spec1 = block_spec(bcfg, x.shape[-1])
 
@@ -89,8 +88,7 @@ def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3
         )
         y, c2 = block_apply(p, x, bcfg, cache=c, positions=positions, pos3d=pos3d,
                             odin=odin, norm_eps=norm_eps, moe_no_drop=moe_no_drop,
-                            tables=tables, spec_decode=spec_decode, q_lens=q_lens,
-                            q_decode=q_decode)
+                            tables=tables, spec_decode=spec_decode, mixed=mixed)
         # pin the scanned activation sharding so carry propagation never
         # settles on "replicated" (no-op outside a logical_sharding context)
         y = constrain(y, ("batch", "act_seq", None))
@@ -109,7 +107,7 @@ def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3
 
 def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
             pos3d=None, start_pos=None, moe_no_drop: bool = False, tables=None,
-            spec_decode: bool = False, q_lens=None, q_decode=None):
+            spec_decode: bool = False, mixed=None):
     """tokens: [B,S] (or [B,K,S] multi-codebook) → (logits, new_caches).
 
     logits: [B,S,V] (or [B,S,K,V]).  ``caches``: list of per-segment stacked
@@ -121,13 +119,12 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
     block pool (one table serves every layer; scan-invariant).
     ``spec_decode``: the S tokens are an in-flight speculative draft —
     paged attention runs the multi-token-query decode kernel instead of the
-    prefill gather path.  ``q_lens``: int32 [B] real-row counts of a mixed
-    prefill+decode tile, right-aligned in the S rows (paged GQA caches
-    only); ``start_pos`` should then be the per-slot position of row 0
-    (pad rows get earlier — possibly negative — positions, which is fine:
-    their output is discarded and their KV writes go to the write-off
-    block); ``q_decode`` [B] bool flags the slots whose single real row is
-    a decode step and must take the decode kernel's numerics.
+    prefill gather path.  ``mixed``: the row layout
+    (``nn.attention.MixedRows``) of a mixed prefill+decode dispatch (paged
+    GQA caches only): ``tokens`` are its flat rows ``[1, N]`` (or
+    ``[1, K, N]``), ``start_pos`` is the per-slot cached length [B] before
+    the dispatch, and logits come back only for the rows that are read,
+    ``[1, B + L, V]`` (:meth:`MixedRows.head_rows`).
     """
     odin = _odin(cfg)
     if cfg.n_codebooks > 1:
@@ -144,7 +141,10 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
 
     start = jnp.int32(0) if start_pos is None else start_pos
     B, S = x.shape[0], x.shape[1]
-    positions = start + jnp.arange(S, dtype=jnp.int32)[None, :] + jnp.zeros((B, 1), jnp.int32)
+    if mixed is not None:
+        positions = mixed.positions(start, S)
+    else:
+        positions = start + jnp.arange(S, dtype=jnp.int32)[None, :] + jnp.zeros((B, 1), jnp.int32)
 
     new_caches = []
     for i, bcfg in enumerate(cfg.blocks):
@@ -157,10 +157,12 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
             x, c2 = _segment_apply(params["segments"][i], x, bcfg, c, positions, pos3d,
                                    odin, cfg.remat, cfg.norm_eps, moe_no_drop,
                                    tables=tables, spec_decode=spec_decode,
-                                   q_lens=q_lens, q_decode=q_decode)
+                                   mixed=mixed)
             new_caches.append(c2)
 
     hidden = x
+    if mixed is not None:
+        x = x[:, mixed.head_rows(S)]      # the head runs on read rows only
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     if cfg.n_codebooks > 1:

@@ -25,7 +25,7 @@ and visibility masks, so one fixed-shape decode serves mixed-length slots).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +38,7 @@ from repro.nn.layers import apply_mrope, apply_rope, linear, linear_spec, norm_s
 from repro.nn.module import ParamSpec
 
 __all__ = ["attn_spec", "attention", "init_cache", "init_paged_cache",
-           "DEFAULT_CHUNK", "KV_SCALE", "POOL_LEAVES"]
+           "MixedRows", "DEFAULT_CHUNK", "KV_SCALE", "POOL_LEAVES"]
 
 # Cache-leaf names of the paged physical KV store (block-pool layout); shared
 # by the serving step/swap machinery to tell pool leaves (no slot axis) from
@@ -251,8 +251,131 @@ def _positions(batch: int, start, seq: int):
     return start + jnp.arange(seq, dtype=jnp.int32)[None, :] + jnp.zeros((batch, 1), jnp.int32)
 
 
+class MixedRows(NamedTuple):
+    """Row layout of one mixed prefill+decode dispatch (paged GQA caches).
+
+    The dispatch's activations are one flat row axis ``[1, B + L·Q]``:
+
+    * rows ``0 … B-1`` are the **decode group**, one row per slot — slot
+      ``b``'s pending token when ``decode[b]``, else a pad row;
+    * then ``L`` **prefill lanes** of ``Q`` rows: lane ``l`` carries
+      ``lane_lens[l]`` replay tokens of slot ``lane_slot[l]``, right-aligned
+      (its last real row is the lane's last row), pad rows before them.  An
+      empty lane has ``lane_lens = 0``.
+
+    Everything but attention (embedding, norms, projections, MLP, drop-free
+    MoE) runs on the flat rows, so weights stream once for the whole
+    dispatch; :func:`_paged_mixed` splits the rows back into the groups.
+    A slot is in at most one group per dispatch.
+    """
+    decode: jax.Array       # [B] bool
+    lane_slot: jax.Array    # [L] int32
+    lane_lens: jax.Array    # [L] int32
+
+    @staticmethod
+    def flat_tokens(dec_tokens, lane_tokens) -> jax.Array:
+        """The flat rows ``[1, N]`` (or ``[1, K, N]``) from the decode
+        group's tokens ``[B]`` (or ``[B, K]``) and the lanes' ``[L, Q]``
+        (or ``[L, K, Q]``)."""
+        if lane_tokens.ndim == 3:                        # K codebooks
+            L, K, Q = lane_tokens.shape
+            lanes = lane_tokens.transpose(1, 0, 2).reshape(K, L * Q)
+            return jnp.concatenate([dec_tokens.T, lanes], axis=1)[None]
+        return jnp.concatenate([dec_tokens, lane_tokens.reshape(-1)])[None]
+
+    def _dims(self, n_rows: int):
+        B, L = self.decode.shape[0], self.lane_slot.shape[0]
+        return B, L, (n_rows - B) // L
+
+    def slot_rows(self) -> jax.Array:
+        """[B] real rows each slot carries (its cache ``pos`` advance)."""
+        return self.decode.astype(jnp.int32).at[self.lane_slot].add(
+            self.lane_lens)
+
+    def real(self, n_rows: int) -> jax.Array:
+        """[N] bool: which flat rows carry a token."""
+        B, L, Q = self._dims(n_rows)
+        lane = jnp.arange(Q)[None, :] >= (Q - self.lane_lens)[:, None]
+        return jnp.concatenate([self.decode, lane.reshape(-1)])
+
+    def positions(self, lengths, n_rows: int) -> jax.Array:
+        """[1, N] absolute position of every flat row, from the per-slot
+        cached ``lengths`` [B] before the dispatch (pad rows of a lane get
+        earlier, possibly negative, positions: invisible keys, discarded)."""
+        B, L, Q = self._dims(n_rows)
+        lane = (lengths[self.lane_slot][:, None] + jnp.arange(Q)[None, :]
+                - (Q - self.lane_lens)[:, None])
+        return jnp.concatenate([lengths, lane.reshape(-1)])[None, :]
+
+    def slots(self, n_rows: int) -> jax.Array:
+        """[N] the slot whose table each flat row writes through."""
+        B, L, Q = self._dims(n_rows)
+        return jnp.concatenate([jnp.arange(B, dtype=jnp.int32),
+                                jnp.repeat(self.lane_slot, Q)])
+
+    def head_rows(self, n_rows: int) -> jax.Array:
+        """[B + L] the rows whose logits are read: every decode-group row,
+        then each lane's last row."""
+        B, L, Q = self._dims(n_rows)
+        return jnp.concatenate([jnp.arange(B, dtype=jnp.int32),
+                                B + Q * jnp.arange(L, dtype=jnp.int32) + Q - 1])
+
+
+def _paged_mixed(q, k, v, cfg: AttnConfig, positions, cache, tables,
+                 rows: MixedRows):
+    """Mixed dispatch through the block pool: per-row K/V writes, then the
+    decode group through the Pallas kernel and each lane through the
+    chunked-prefill gather+sdpa core (see :class:`MixedRows`).
+
+    Each group makes exactly the call its dedicated path makes — the decode
+    group the decode program's ``[slots]`` kernel call, each lane the
+    chunked prefill's ``[1, c]`` gather over one slot's table, masked at its
+    new length — so greedy mixed-on streams stay token-for-token equal to
+    mixed-off (the two cores round differently; neither may stand in for
+    the other).  Pad rows write to the pool's write-off block, and every
+    key stays invisible to them.
+    """
+    N = q.shape[1]
+    B, L, Q = rows._dims(N)
+    P = tables.shape[1]
+    pos = cache["pos"]
+    kp, vp = cache["k_pool"], cache["v_pool"]
+    cdt = kp.dtype
+    bs, Hkv, D = kp.shape[1], kp.shape[2], kp.shape[3]
+    trash = jnp.int32(kp.shape[0] - 1)
+    row_pos = positions[0]
+    real = rows.real(N)
+    page = jnp.where(real, row_pos // bs, jnp.int32(P))
+    bids = tables[rows.slots(N), jnp.minimum(page, P - 1)]
+    bids = jnp.where(page >= P, trash, bids)
+    at = jnp.where(real, row_pos % bs, 0)
+    kp = kp.at[bids, at].set(_cache_write(k[0], cdt))
+    vp = vp.at[bids, at].set(_cache_write(v[0], cdt))
+    new_len = pos + rows.slot_rows()
+    new_cache = {"k_pool": kp, "v_pool": vp, "pos": new_len}
+    kv_scale = KV_SCALE if cdt == jnp.int8 else None
+    # decode group: the decode program's kernel call (tables of slots that
+    # are not decoding point at the write-off block, as they do there)
+    dec_tables = jnp.where(rows.decode[:, None], tables, trash)
+    od = paged_attention(q[0, :B], kp, vp, dec_tables, new_len,
+                         window=cfg.window, kv_scale=kv_scale)
+    od = jnp.where(rows.decode[:, None, None], od, 0)
+    # prefill lanes: the chunked prefill's gather of one slot's pages, keys
+    # masked at the slot's new length, sdpa
+    lane_tables = tables[rows.lane_slot]                          # [L, P]
+    ck = _cache_read(kp[lane_tables].reshape(L, P * bs, Hkv, D), q.dtype)
+    cv = _cache_read(vp[lane_tables].reshape(L, P * bs, Hkv, D), q.dtype)
+    slot_rows = jnp.arange(P * bs, dtype=jnp.int32)[None, :]
+    k_pos = jnp.where(slot_rows < new_len[rows.lane_slot][:, None], slot_rows,
+                      jnp.int32(2**30))
+    ol = sdpa(q[0, B:].reshape(L, Q, -1, D), ck, cv,
+              row_pos[B:].reshape(L, Q), k_pos, cfg.window)
+    o = jnp.concatenate([od, ol.reshape(L * Q, -1, D)])[None]
+    return o, new_cache
+
+
 def _paged_gqa_core(q, k, v, cfg: AttnConfig, positions, cache, tables,
-                    spec_decode: bool = False, q_lens=None, q_decode=None):
+                    spec_decode: bool = False):
     """Write the new K/V rows into the block pool and attend through it.
 
     ``pos`` must be a per-slot [B] vector (paged caches exist only in the
@@ -263,69 +386,22 @@ def _paged_gqa_core(q, k, v, cfg: AttnConfig, positions, cache, tables,
     per-token hot path, and its cost is O(max_len) regardless).  A
     speculative verify (``spec_decode``, small S = draft+1) keeps the kernel
     path with an S-row query tile instead — per-token decode semantics, no
-    O(max_len) gather in the per-dispatch hot loop.
-
-    ``q_lens`` (mixed prefill+decode dispatch): int32 [B] of real query rows
-    per slot, right-aligned in the S-row tile — slot b's q_lens[b] real
-    tokens occupy rows S-q_lens[b]..S-1 so ``logits[:, -1]`` is the last
-    real token for every slot regardless of its q_len.  Pad rows write to
-    the pool's write-off block and their (lower, possibly negative) query
-    positions make every key invisible to them, so no real row ever reads a
-    pad row and pad-row outputs are discarded by the caller.  ``pos``
-    advances by ``q_lens``.
-
-    Bit-identity is the contract, so the mixed tile runs BOTH attention
-    implementations and selects per slot: prefill slots take the same
-    gather+sdpa core the dedicated chunked-prefill path uses (per-row
-    results are chunk- and batch-shape-invariant there), while slots flagged
-    in ``q_decode`` [B] take a single-row Pallas kernel call on the tile's
-    last column — exactly the dedicated decode dispatch's call.  One
-    implementation for both populations would be cheaper but would flip
-    greedy argmaxes on logit ties (the two cores round differently), and
-    mixed-on streams must equal mixed-off streams token for token.
+    O(max_len) gather in the per-dispatch hot loop.  A mixed dispatch — a
+    ``[slots, 1]`` decode group plus ``[1, Q]`` prefill lanes — takes
+    :func:`_paged_mixed`, which makes this function's S == 1 kernel call
+    for the decode group and its chunked-prefill gather+sdpa per lane.
 
     Writes for rows at or past the table's page span (a verify tile near a
     slot's ``max_len``, where rejected draft rows may overhang the budget)
     are redirected to the pool's write-off block — reading a stale table
     entry there could alias another slot's live block.
     """
-    if tables is None:
-        raise ValueError("paged attention cache requires block tables")
     B, S = q.shape[0], q.shape[1]
     P = tables.shape[1]
     pos = cache["pos"]
     kp, vp = cache["k_pool"], cache["v_pool"]
     cdt = kp.dtype
     bs = kp.shape[1]
-    if q_lens is not None:
-        idx = jnp.arange(S, dtype=jnp.int32)[None, :]
-        off = (S - q_lens)[:, None]                                # pad rows
-        rows = pos[:, None] + idx - off                            # [B, S]
-        page = jnp.where(idx >= off, rows // bs, jnp.int32(P))
-        bids = jnp.take_along_axis(tables, jnp.minimum(page, P - 1), axis=1)
-        bids = jnp.where(page >= P, jnp.int32(kp.shape[0] - 1), bids)
-        slot = jnp.where(idx >= off, rows % bs, 0)
-        kp = kp.at[bids, slot].set(_cache_write(k, cdt))
-        vp = vp.at[bids, slot].set(_cache_write(v, cdt))
-        new_cache = {"k_pool": kp, "v_pool": vp, "pos": pos + q_lens}
-        kv_scale = KV_SCALE if cdt == jnp.int8 else None
-        # prefill rows: the dedicated chunked-prefill numerics (gather the
-        # table's pages once, mask keys at the slot's new length, sdpa)
-        Hkv, D = kp.shape[2], kp.shape[3]
-        ck = _cache_read(kp[tables].reshape(B, P * bs, Hkv, D), q.dtype)
-        cv = _cache_read(vp[tables].reshape(B, P * bs, Hkv, D), q.dtype)
-        slot_rows = jnp.arange(P * bs, dtype=jnp.int32)[None, :]
-        k_pos = jnp.where(slot_rows < (pos + q_lens)[:, None], slot_rows,
-                          jnp.int32(2**30))
-        o = sdpa(q, ck, cv, positions, k_pos, cfg.window)
-        if q_decode is not None:
-            # decode rows: the dedicated decode dispatch's kernel call on
-            # the tile's last column (their only real row)
-            od = paged_attention(q[:, -1], kp, vp, tables, pos + q_lens,
-                                 window=cfg.window, kv_scale=kv_scale)
-            last = jnp.where(q_decode[:, None, None], od, o[:, -1])
-            o = jnp.concatenate([o[:, :-1], last[:, None]], axis=1)
-        return o, new_cache
     rows = pos[:, None] + jnp.arange(S, dtype=jnp.int32)           # [B, S]
     page = rows // bs
     bids = jnp.take_along_axis(tables, jnp.minimum(page, P - 1), axis=1)
@@ -353,8 +429,7 @@ def _paged_gqa_core(q, k, v, cfg: AttnConfig, positions, cache, tables,
 
 
 def _gqa_attention(p, x, cfg: AttnConfig, positions, pos3d, cache, odin,
-                   tables=None, spec_decode: bool = False, q_lens=None,
-                   q_decode=None):
+                   tables=None, spec_decode: bool = False, mixed=None):
     B, S, _ = x.shape
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = linear(x, p["q"], odin).reshape(B, S, H, D)
@@ -375,9 +450,14 @@ def _gqa_attention(p, x, cfg: AttnConfig, positions, pos3d, cache, odin,
         o = sdpa(q, k, v, positions, k_pos, cfg.window)
         new_cache = None
     elif "k_pool" in cache:
-        o, new_cache = _paged_gqa_core(q, k, v, cfg, positions, cache, tables,
-                                       spec_decode=spec_decode, q_lens=q_lens,
-                                       q_decode=q_decode)
+        if tables is None:
+            raise ValueError("paged attention cache requires block tables")
+        if mixed is not None:
+            o, new_cache = _paged_mixed(q, k, v, cfg, positions, cache, tables,
+                                        mixed)
+        else:
+            o, new_cache = _paged_gqa_core(q, k, v, cfg, positions, cache,
+                                           tables, spec_decode=spec_decode)
     else:
         pos = cache["pos"]
         size = cache["k"].shape[1]
@@ -489,19 +569,18 @@ def _mla_attention(p, x, cfg: AttnConfig, positions, cache, odin):
 
 def attention(p, x, cfg: AttnConfig, positions=None, pos3d=None, cache=None,
               odin: Optional[OdinConfig] = None, tables=None,
-              spec_decode: bool = False, q_lens=None, q_decode=None):
+              spec_decode: bool = False, mixed: Optional[MixedRows] = None):
     """Returns (output [B,S,d_model], new_cache).  ``tables`` are the per-slot
     block tables of the paged serving cache (ignored by dense/MLA caches).
     ``spec_decode``: the S tokens are an in-flight speculative draft — paged
     caches attend through the multi-token-query kernel instead of the prefill
     gather (dense/MLA caches already handle S > 1 with decode semantics).
-    ``q_lens``: per-slot real-row counts of a mixed prefill+decode tile
-    (right-aligned; paged GQA caches only); ``q_decode`` [B] bool flags the
-    slots whose single real row is a decode step and must take the decode
-    kernel's numerics — see :func:`_paged_gqa_core`."""
+    ``mixed``: the row layout of a mixed prefill+decode dispatch, whose
+    rows are flat in ``x [1, N, d]`` (paged GQA caches only; ``positions``
+    then come from :meth:`MixedRows.positions`) — see :class:`MixedRows`."""
     B, S, _ = x.shape
-    if q_lens is not None and (cache is None or "k_pool" not in cache):
-        raise ValueError("q_lens (mixed dispatch) requires a paged GQA cache")
+    if mixed is not None and (cache is None or "k_pool" not in cache):
+        raise ValueError("mixed dispatch requires a paged GQA cache")
     if positions is None:
         start = cache["pos"] if cache is not None else jnp.int32(0)
         if getattr(start, "ndim", 0) == 1:      # per-slot positions [B]
@@ -510,5 +589,4 @@ def attention(p, x, cfg: AttnConfig, positions=None, pos3d=None, cache=None,
     if cfg.kind == "mla":
         return _mla_attention(p, x, cfg, positions, cache, odin)
     return _gqa_attention(p, x, cfg, positions, pos3d, cache, odin, tables,
-                          spec_decode=spec_decode, q_lens=q_lens,
-                          q_decode=q_decode)
+                          spec_decode=spec_decode, mixed=mixed)

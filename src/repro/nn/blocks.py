@@ -98,22 +98,21 @@ def block_cache(cfg: BlockConfig, d_model: int, batch: int, max_len: int, dtype=
 def block_apply(p, x, cfg: BlockConfig, cache=None, positions=None, pos3d=None,
                 odin: Optional[OdinConfig] = None, norm_eps: float = 1e-5,
                 moe_no_drop: bool = False, tables=None,
-                spec_decode: bool = False, q_lens=None, q_decode=None):
+                spec_decode: bool = False, mixed=None):
     """(params, x [B,S,d], cache) → (x', cache').  ``tables``: per-slot block
     tables when the attention cache is the paged block pool (serving);
     ``spec_decode``: the S tokens are a speculative draft tile (paged
-    attention takes the multi-token-query kernel path); ``q_lens``: per-slot
-    real-row counts of a mixed prefill+decode tile (paged GQA only), with
-    ``q_decode`` flagging the slots that need decode-kernel numerics."""
+    attention takes the multi-token-query kernel path); ``mixed``: the row
+    layout (``nn.attention.MixedRows``) of a mixed prefill+decode dispatch
+    whose rows are flat in ``x [1, N, d]`` (paged GQA only)."""
     new_cache = dict(cache) if cache is not None else None
-    if q_lens is not None and cfg.kind not in ("dense", "moe"):
-        raise ValueError("mixed dispatch (q_lens) supports paged GQA blocks only")
+    if mixed is not None and cfg.kind not in ("dense", "moe"):
+        raise ValueError("mixed dispatch supports paged GQA blocks only")
     if cfg.kind in ("dense", "moe"):
         a, ac = attention(p["attn"], rmsnorm(x, p["ln1"], norm_eps), cfg.attn,
                           positions=positions, pos3d=pos3d,
                           cache=None if cache is None else cache["attn"], odin=odin,
-                          tables=tables, spec_decode=spec_decode, q_lens=q_lens,
-                          q_decode=q_decode)
+                          tables=tables, spec_decode=spec_decode, mixed=mixed)
         x = x + a
         h = rmsnorm(x, p["ln2"], norm_eps)
         if cfg.kind == "dense":

@@ -189,7 +189,10 @@ class ServingEngine:
     mixed_budget : total query rows per mixed dispatch (default
         ``prefill_chunk + slots``: every decode slot rides along at full
         chunk-rate prefill progress).  Decode rows are packed first; one
-        row is always reserved for the oldest mid-prefill slot.
+        row is always reserved for the oldest mid-prefill slot.  It also
+        fixes the mixed program's prefill lanes, ``max(1, (mixed_budget -
+        slots) // prefill_chunk)`` (1 at the default): at most that many
+        prompts progress per dispatch, each in a lane of its own.
     jit_cache : max fused decode executables kept compiled (LRU over
         (horizon, spec) grants; evictions counted in ``EngineStats``).
     jit_cache : max fused decode executables kept compiled (LRU over
@@ -420,7 +423,7 @@ class ServingEngine:
         # prompt KV through the block tables, so every cache leaf must be the
         # pool (or the `pos` counter the mixed step re-derives).  Dense ring
         # or recurrent state would need per-slot multi-row advances the
-        # [slots, Q] tile cannot express for heterogeneous q_lens.
+        # flat mixed rows cannot express for heterogeneous row counts.
         if mixed is None:
             mixed = fully_paged
         elif mixed and not fully_paged:
@@ -436,6 +439,7 @@ class ServingEngine:
             raise ValueError(
                 f"mixed_budget must be >= 2 (one decode row plus one prefill "
                 f"row), got {self.mixed_budget}")
+        self.lanes = max(1, (self.mixed_budget - slots) // self.chunk)
         self._mixed: Optional[Callable] = None      # lazily jitted
         prefix_cache = (PrefixCache(self.pool, block_size)
                         if self.prefix_sharing else None)
@@ -1161,7 +1165,7 @@ class ServingEngine:
 
     def _mixed_fn(self) -> Callable:
         """Lazily-jitted mixed prefill+decode step.  One jit object; XLA
-        retraces per tile width Q, and the engine snaps Q to the next power
+        retraces per lane width Q, and the engine snaps Q to the next power
         of two so the executable count is bounded by log2(chunk)+1."""
         if self._mixed is None:
             self._mixed = jax.jit(
@@ -1171,41 +1175,41 @@ class ServingEngine:
         return self._mixed
 
     def _dispatch_mixed(self) -> None:
-        """ONE fused dispatch over both populations: decode slots at
-        ``q_len = 1`` plus mid-prefill slots at ``q_len ≤ chunk``, packed by
-        ``Scheduler.pack_mixed`` under the ``mixed_budget`` row budget.
+        """ONE fused dispatch over both populations: decode slots at one row
+        each plus up to ``lanes`` mid-prefill slots at ``≤ chunk`` rows each,
+        packed by ``Scheduler.pack_mixed`` under the ``mixed_budget`` row
+        budget.  The program runs ``slots + lanes·Q`` rows (a decode group
+        and the prefill lanes, ``nn.attention.MixedRows``), Q the widest
+        part snapped to a power of two.
 
         Decode rows emit exactly what the single-step path would have
-        emitted (the kernel's per-row online softmax makes each query row
-        independent, and right alignment puts every slot's last real token
-        at column Q-1); a prefill slot whose replay completes here gets its
-        first token from ``last_logits`` through the same host-side
-        ``_first_token`` path as the separate prefill — greedy mixed-on
-        streams are bit-identical to mixed-off."""
+        emitted (the decode group makes the decode program's kernel call);
+        a prefill slot whose replay completes here gets its first token from
+        ``last_logits`` through the same host-side ``_first_token`` path as
+        the separate prefill — greedy mixed-on streams are bit-identical to
+        mixed-off."""
         with self._phase("pack"):
             decode, parts = self.sched.pack_mixed(self.mixed_budget,
-                                                  self.chunk)
+                                                  self.chunk, self.lanes)
             if not decode and not parts:
                 return
             q_max = max([1] + [c for _, _, c in parts])
-            Q = 1 << (q_max - 1).bit_length()   # pow-2 tile widths, bounded
-            K = self.cfg.n_codebooks
-            tok = np.zeros((self.slots, K, Q) if K > 1 else (self.slots, Q),
-                           np.int32)
-            q_lens = np.zeros(self.slots, np.int32)
-            active = np.zeros(self.slots, bool)
-            dm = np.zeros(self.slots, bool)
+            Q = 1 << (q_max - 1).bit_length()   # pow-2 lane widths, bounded
+            B, L, K = self.slots, self.lanes, self.cfg.n_codebooks
+            n_rows = B + L * Q
+            dec_tok = np.zeros((B, K) if K > 1 else B, np.int32)
+            lane_tok = np.zeros((L, K, Q) if K > 1 else (L, Q), np.int32)
+            dm = np.zeros(B, bool)
+            lane_slot = np.zeros(L, np.int32)
+            lane_lens = np.zeros(L, np.int32)
             for r in decode:
-                active[r.slot] = True
                 dm[r.slot] = True
-                q_lens[r.slot] = 1
                 # the pending token is host-resident in the stream — no
-                # device readback of _last_tok needed to build the tile
-                tok[r.slot, ..., -1] = np.asarray(r.generated[-1], np.int32)
-            for r, start, c in parts:
-                active[r.slot] = True
-                q_lens[r.slot] = c
-                tok[r.slot, ..., Q - c:] = np.asarray(
+                # device readback of _last_tok needed to build the rows
+                dec_tok[r.slot] = np.asarray(r.generated[-1], np.int32)
+            for lane, (r, start, c) in enumerate(parts):
+                lane_slot[lane], lane_lens[lane] = r.slot, c
+                lane_tok[lane, ..., Q - c:] = np.asarray(   # right-aligned
                     r.replay_tokens(), np.int32)[..., start:start + c]
         t0 = self._now()            # engine clock: metrics ≡ stats ≡ trace
         with self._phase("tables"):
@@ -1213,9 +1217,10 @@ class ServingEngine:
         with self._phase("mixed"):
             key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
             nxt, last_logits, self.caches = self._mixed_fn()(
-                self.params, self.caches, jnp.asarray(tok),
-                jnp.asarray(self._slot_len), jnp.asarray(q_lens),
-                jnp.asarray(dm), jnp.asarray(active), tables, key,
+                self.params, self.caches, jnp.asarray(dec_tok),
+                jnp.asarray(lane_tok), jnp.asarray(self._slot_len),
+                jnp.asarray(dm),
+                jnp.asarray(lane_slot), jnp.asarray(lane_lens), tables, key,
                 jnp.float32(self.temperature))
         with self._phase("sync"):
             host = np.asarray(nxt)                   # syncs the step
@@ -1241,11 +1246,14 @@ class ServingEngine:
             self.stats.mixed_dispatches += 1
             self.stats.mixed_decode_rows += dec_rows
             self.stats.mixed_prefill_rows += pre_rows
+            self.stats.mixed_tile_rows += n_rows
+            self.stats.mixed_prefill_deferred += self.sched.lane_deferred
             if self.tracer.enabled:
                 self.tracer.span(
                     "mixed", "dispatch", "dispatch", t0, wall,
-                    args={"kind": "mixed", "q_tile": Q,
-                          "slots_active": int(active.sum()),
+                    args={"kind": "mixed", "q_tile": Q, "tile_rows": n_rows,
+                          "lanes": L,
+                          "slots_active": len(decode) + len(parts),
                           "decode_rows": dec_rows, "prefill_rows": pre_rows,
                           "tokens": dec_rows, "rows": rows, "host_syncs": 1,
                           "odin_energy_mj": self.cost_model.energy_mj(rows)})

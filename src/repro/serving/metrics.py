@@ -54,6 +54,8 @@ class EngineStats:
     mixed_dispatches: int = 0             # fused prefill+decode launches
     mixed_decode_rows: int = 0            # decode rows carried by mixed tiles
     mixed_prefill_rows: int = 0           # prefill rows carried by mixed tiles
+    mixed_tile_rows: int = 0              # rows the mixed program ran (slots + lanes·Q)
+    mixed_prefill_deferred: int = 0       # mixed dispatches the lane cap held a prompt back
     swap_skipped_blocks: int = 0          # swap-out copies skipped (re-attach)
     jit_evictions: int = 0                # fused executables dropped (LRU)
     timeouts: int = 0                     # requests expired (deadline/queue)
@@ -259,6 +261,8 @@ def summarize(requests, stats: EngineStats, cost: Optional[OdinCostModel] = None
             "dispatches": stats.mixed_dispatches,
             "decode_rows": stats.mixed_decode_rows,
             "prefill_rows": stats.mixed_prefill_rows,
+            "tile_rows": stats.mixed_tile_rows,
+            "prefill_deferred": stats.mixed_prefill_deferred,
         },
         "jit_evictions": stats.jit_evictions,
         # terminal-state matrix: every request ends in exactly one of these

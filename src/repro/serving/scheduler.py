@@ -462,6 +462,9 @@ class Scheduler:
         self.defer_prefix_register: bool = False
         # round-robin cursor for decode rows under mixed-budget scarcity
         self._mixed_rr: int = 0
+        # whether the last pack_mixed held a prefilling slot back for want
+        # of a lane while budget rows were left
+        self.lane_deferred: bool = False
         # preemption-victim policy hook: a key function over running requests
         # (max wins).  None keeps the default youngest-first ``(arrival,
         # rid)`` order; the front door installs a QoS-aware key that ranks
@@ -934,7 +937,7 @@ class Scheduler:
 
     # -- mixed prefill+decode packing ---------------------------------------
 
-    def pack_mixed(self, budget: int, chunk: int
+    def pack_mixed(self, budget: int, chunk: int, lanes: int = 1
                    ) -> Tuple[List[Request], List[Tuple[Request, int, int]]]:
         """Pack one fused dispatch under a total query-row ``budget``.
 
@@ -953,6 +956,13 @@ class Scheduler:
         mid-prefill, one row is reserved for the oldest prefilling slot so
         prefill always progresses ≥ 1 row per dispatch (TTFT cannot starve
         behind decode either).
+
+        Lane cap: the mixed program runs each prefill part in a lane of its
+        own (``nn.attention.MixedRows``), and has ``lanes`` of them, so at
+        most ``lanes`` prefilling slots get a part, oldest first.  A further
+        prefilling slot waits for the next dispatch rather than filling a
+        short part's leftover rows; ``lane_deferred`` records whether this
+        pack held one back while budget rows were left.
 
         Pure bookkeeping — no allocation happens here: admission already
         allocated the full replay footprint (``cached_len + 1`` rows), so
@@ -976,12 +986,16 @@ class Scheduler:
                 self._mixed_rr = (i0 + cap) % len(order)
             rows_left -= len(decode)
         parts: List[Tuple[Request, int, int]] = []
+        self.lane_deferred = False
         for r in prefilling:
             if rows_left <= 0:
                 break
             c = min(chunk, r.cached_len - r.prefill_pos, rows_left)
             if c <= 0:
                 continue
+            if len(parts) == lanes:
+                self.lane_deferred = True
+                break
             parts.append((r, r.prefill_pos, c))
             rows_left -= c
         return decode, parts

@@ -17,7 +17,7 @@ Three layers:
 import numpy as np
 import pytest
 
-from serving_harness import materialize, mixed_spec, run_workload
+from serving_harness import materialize, mixed_spec, run_workload, token_streams
 
 from repro.serving import Request, ServingEngine, Tracer, make_requests
 from repro.serving.blocks import BlockPool
@@ -112,6 +112,32 @@ def test_pack_mixed_decode_starvation_bounded():
             assert t - last < bound, f"slot {s} starved {t - last} dispatches"
 
 
+def test_pack_mixed_lane_cap():
+    """Property: no dispatch carries more prefill parts than the program has
+    lanes, the oldest prefilling slot always holds a lane, and
+    ``lane_deferred`` is set exactly when a further prefilling slot was held
+    back while budget rows were left."""
+    rng = np.random.default_rng(1)
+    for _ in range(300):
+        nd = int(rng.integers(0, 7))
+        rems = [int(rng.integers(1, 40)) for _ in range(rng.integers(0, 5))]
+        sched = _sched_with(nd, rems)
+        budget = int(rng.integers(1, 48))
+        chunk = int(rng.integers(1, 16))
+        lanes = int(rng.integers(1, 4))
+        decode, parts = sched.pack_mixed(budget, chunk, lanes)
+        assert len(parts) <= lanes
+        assert len({r.slot for r, _, _ in parts}) == len(parts)
+        prefilling = sorted((r for r in sched.running.values()
+                             if r.prefilling), key=lambda r: r.arrival)
+        if prefilling:
+            assert parts and parts[0][0] is prefilling[0]
+        rows = len(decode) + sum(c for _, _, c in parts)
+        held = len(prefilling) > len(parts)
+        assert sched.lane_deferred == (held and len(parts) == lanes
+                                       and rows < max(1, budget))
+
+
 # ---------------------------------------------------------------------------
 # end-to-end parity (jax)
 # ---------------------------------------------------------------------------
@@ -165,6 +191,89 @@ def test_engine_mixed_budget_throttles_rows():
     assert base == small
     assert ss["mixed"]["dispatches"] > sb["mixed"]["dispatches"]
     assert ss["mixed"]["prefill_rows"] == sb["mixed"]["prefill_rows"]
+
+
+def _overlapping(**kw):
+    # every prompt arrives at once and spans several chunks, so prefills
+    # overlap: the lane cap must hold the younger prompts back
+    return mixed_spec(n_requests=5, prompt_buckets=(20, 28), gen_buckets=(4, 9),
+                      **kw)
+
+
+@pytest.mark.parametrize("mixed_budget", [None, 2 * 8 + 3])
+def test_engine_mixed_lanes_parity(mixed_budget):
+    """Overlapping prefills under one lane (the default budget) and under
+    two: younger prompts wait for a lane (``mixed_prefill_deferred``), the
+    program runs ``slots + lanes·q_tile`` rows per dispatch, and greedy
+    streams still equal mixed-off."""
+    cfg, params = materialize("phi4-mini-3.8b")
+    base, _ = run_workload(cfg, params, spec=_overlapping(), mixed=False,
+                           prefill_chunk=8)
+    tracer = Tracer()
+    eng = ServingEngine(cfg, slots=3, max_len=48, block_size=8, params=params,
+                        prefill_chunk=8, mixed=True, mixed_budget=mixed_budget,
+                        tracer=tracer)
+    reqs = make_requests(cfg, _overlapping(), seed=9)
+    eng.run(reqs)
+    tracer.detach()
+    assert token_streams(reqs) == base
+    lanes = 1 if mixed_budget is None else 2
+    assert eng.lanes == lanes
+    spans = [ev for ev in tracer.events()
+             if ev.ph == "X" and ev.name == "mixed"]
+    st = eng.stats
+    assert len(spans) == st.mixed_dispatches > 0
+    assert st.mixed_prefill_deferred > 0
+    assert all(ev.args["lanes"] == lanes for ev in spans)
+    assert st.mixed_tile_rows == sum(3 + lanes * ev.args["q_tile"]
+                                     for ev in spans)
+    assert st.mixed_tile_rows == sum(ev.args["tile_rows"] for ev in spans)
+    assert max(ev.args["prefill_rows"] for ev in spans) > 8 * (lanes - 1)
+
+
+def _dot_rows(jaxpr):
+    """Row counts of every weight matmul (a dot_general with no batch
+    dimensions) in a jaxpr and its sub-jaxprs: the lhs's elements over its
+    contracted size."""
+    import jax
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (lc, _), (lb, _) = eqn.params["dimension_numbers"]
+            if not lb:
+                shape = eqn.invars[0].aval.shape
+                k = int(np.prod([shape[d] for d in lc]))
+                out.append(int(np.prod(shape)) // k)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _dot_rows(sub)
+    return out
+
+
+def test_mixed_program_runs_only_carried_rows():
+    """At 8 slots and a 512-row lane the mixed program's matmuls run at most
+    ``slots + Q`` = 520 rows (the decode group plus one lane), never the
+    padded 8 × 512 = 4,096-row tile, and the LM head runs on the 9 rows
+    whose logits are read."""
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.steps import init_serving_caches, make_serving_mixed_step
+    from repro.models import lm as lm_mod, registry
+    from repro.nn import module as nnmod
+    cfg = registry.get_smoke("phi4-mini-3.8b")
+    slots, Q, bs, max_len = 8, 512, 16, 1024
+    params = nnmod.abstract(lm_mod.param_spec(cfg))
+    caches = jax.eval_shape(lambda: init_serving_caches(
+        cfg, slots, max_len, block_size=bs, n_blocks=slots * max_len // bs))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    step = make_serving_mixed_step(cfg)
+    jaxpr = jax.make_jaxpr(step)(
+        params, caches, i32(slots), i32(1, Q), i32(slots),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_), i32(1), i32(1),
+        i32(slots, max_len // bs))
+    rows = _dot_rows(jaxpr.jaxpr)
+    assert max(rows) == slots + Q
+    assert slots + 1 in rows                 # the head: 8 decode rows + 1 lane
+    assert slots * Q not in rows
 
 
 def test_engine_mixed_eligibility():

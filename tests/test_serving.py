@@ -1271,6 +1271,8 @@ def test_engine_spec_history_stays_aligned_including_fallback():
     while eng.sched.has_work:
         eng.step()
         for slot, req in eng.sched.running.items():
+            if req.prefilling:
+                continue        # a slot's history is seeded when its prompt ends
             ctx = np.concatenate([np.asarray(req.replay_tokens()).ravel(),
                                   np.ravel(req.generated[-1])])
             row = np.asarray(eng._hist[slot])
