@@ -1,15 +1,19 @@
-"""The plain reference: a dense decoder in float32 at the highest precision.
+"""The plain reference's driver: a family's float32 forward, then the LM head.
 
-Written from the published architecture (Phi-3 / Phi-4-mini: pre-norm
-RMSNorm, rotary embeddings in the half-split form, grouped-query attention,
-SwiGLU MLP, residual stream, final RMSNorm, tied or untied LM head) and the
-configuration file's published keys.  It imports nothing of the program; it
-reads the benchmark's own weights by their names in the weight tree.
+A model family (``bench/families/<family>.py``, named by the configuration
+file) supplies ``forward_rows(params, hf, tokens, rows, low)``: its decoder
+written from the published architecture and the configuration file's
+published keys, embedding to final-normed hidden rows.  This module runs it
+and does what every family shares: grouping the sequences, bucketing and
+padding them, the LM head blocked over the vocabulary, and the controls.
+Nothing here imports the program; the weights are the benchmark's own, read
+by their names in the weight tree.  The helpers a family builds its layers
+from (:func:`mm`, :func:`rms`, :func:`rope`, ``Q_BLOCK``) live here.
 
 It runs once the window has closed and the program's state is freed, one
 layer at a time over small groups of whole sequences (prompt + served
-tokens), with attention computed in blocks of query rows, so that it fits
-beside the weights.
+tokens), with attention computed in blocks of ``Q_BLOCK`` query rows, so
+that it fits beside the weights.
 
 :func:`logit_gaps` gives, at every served position, the gap by which the
 served token's logit lies below the reference's best.  With
@@ -22,7 +26,7 @@ reference's gap of the token the control puts first.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +37,7 @@ Q_BLOCK = 256          # query rows per attention block
 V_BLOCK = 16384        # vocabulary columns per LM-head block
 
 
-def _mm(x, w, low):
+def mm(x, w, low):
     """``x @ w`` in float32 at the highest precision, or with both operands
     in ``low`` ("int8" or "fp8": symmetric, scaled per row of ``x`` and per
     column of ``w``, accumulated exactly)."""
@@ -60,11 +64,11 @@ LOW = {"int8": (127.0, jnp.int8, jnp.int32),
        "fp8": (448.0, jnp.float8_e4m3fn, jnp.float32)}
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta, rot):
+def rope(x, pos, theta, rot):
     """Half-split rotary embedding on the first ``rot`` dims of each head."""
     xr, xp = x[..., :rot], x[..., rot:]
     inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
@@ -72,61 +76,6 @@ def _rope(x, pos, theta, rot):
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(xr, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, xp], -1)
-
-
-def _dims(hf: Dict) -> Tuple:
-    """(heads, kv heads, head size, rotated dims, norm eps, rope theta)."""
-    H, Hkv = hf["num_attention_heads"], hf["num_key_value_heads"]
-    D = hf.get("head_dim") or hf["hidden_size"] // H
-    rot = int(D * hf.get("partial_rotary_factor", 1.0))
-    return H, Hkv, D, rot, float(hf["rms_norm_eps"]), float(hf["rope_theta"])
-
-
-def _layer(x, seg, l, dims, low):
-    """One decoder layer on x [n, T, d] (float32), weights of layer ``l``."""
-    H, Hkv, D, rot, eps, theta = dims
-    w = jax.tree.map(lambda a: a[l].astype(jnp.float32), seg)
-    n, T, _ = x.shape
-    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (n, T))
-    h = _rms(x, w["ln1"], eps)
-    q = _rope(_mm(h, w["attn"]["q"], low).reshape(n, T, H, D), pos, theta, rot)
-    k = _rope(_mm(h, w["attn"]["k"], low).reshape(n, T, Hkv, D), pos, theta, rot)
-    v = _mm(h, w["attn"]["v"], low).reshape(n, T, Hkv, D)
-    G = H // Hkv
-    k = jnp.repeat(k, G, axis=2)             # query head j reads kv head j // G
-    v = jnp.repeat(v, G, axis=2)
-    kpos = jnp.arange(T)
-
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK, axis=1)
-        s = jnp.einsum("nqhd,nkhd->nhqk", qb, k, precision=HIGHEST) / np.sqrt(D)
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("nhqk,nkhd->nqhd", p, v, precision=HIGHEST)
-
-    o = jax.lax.map(block, jnp.arange(T // Q_BLOCK))        # [B, n, Qb, H, D]
-    o = jnp.moveaxis(o, 0, 1).reshape(n, T, H * D)
-    x = x + _mm(o, w["attn"]["o"], low)
-    h = _rms(x, w["ln2"], eps)
-    m = w["mlp"]
-    g = jax.nn.silu(_mm(h, m["w_gate"], low)) * _mm(h, m["w_up"], low)
-    return x + _mm(g, m["w_down"], low)
-
-
-_layer_jit = jax.jit(_layer, static_argnames=("dims", "low"))
-
-
-def _final_rows(params, hf, tokens, rows, low):
-    """Final-normed hidden rows ``rows`` ([R] flat indices) of the batch."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-    seg = params["segments"][0]
-    dims = _dims(hf)
-    for l in range(hf["num_hidden_layers"]):
-        x = _layer_jit(x, seg, l, dims=dims, low=low)
-    x = x.reshape(-1, x.shape[-1])[rows]
-    return _rms(x, params["final_norm"].astype(jnp.float32),
-                hf["rms_norm_eps"])
 
 
 def _head(params, hf):
@@ -141,10 +90,12 @@ def _bucket(n: int, least: int) -> int:
     return max(least, 1 << (max(n, 1) - 1).bit_length())
 
 
-def logit_gaps(params, hf: Dict, seqs: Sequence[Tuple[np.ndarray, np.ndarray]],
+def logit_gaps(forward_rows: Callable, params, hf: Dict,
+               seqs: Sequence[Tuple[np.ndarray, np.ndarray]],
                controls: Sequence[str] = (), batch: int = 4
                ) -> Dict[str, np.ndarray]:
-    """Gaps at every served position of ``seqs`` ([(prompt, served)]).
+    """Gaps at every served position of ``seqs`` ([(prompt, served)]), the
+    final rows from the family's ``forward_rows``.
 
     Returns ``gap`` (reference best minus the served token's reference
     logit, one per served token, in order) and, for each precision in
@@ -178,7 +129,7 @@ def logit_gaps(params, hf: Dict, seqs: Sequence[Tuple[np.ndarray, np.ndarray]],
                                       np.int32))
         for low in finals:
             finals[low].append(
-                _final_rows(params, hf, jnp.asarray(tokens), rows, low)[:R])
+                forward_rows(params, hf, jnp.asarray(tokens), rows, low)[:R])
     R = len(served)
     pad = _bucket(R, Q_BLOCK) - R
     back = np.concatenate([np.arange(*_span(pos[i])) for i in range(len(seqs))])
@@ -232,7 +183,7 @@ def _head_cols(head, by_row, idx):
 
 @functools.partial(jax.jit, static_argnames=("low",))
 def _head_step(x, wb, served, v0, best, arg, at_served, low):
-    logits = _mm(x, wb, low)                                  # [R, Vb]
+    logits = mm(x, wb, low)                                  # [R, Vb]
     bmax, bidx = logits.max(-1), logits.argmax(-1).astype(jnp.int32) + v0
     arg = jnp.where(bmax > best, bidx, arg)
     best = jnp.maximum(best, bmax)

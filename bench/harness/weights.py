@@ -9,6 +9,10 @@ program's interface (``lm.param_spec``); the values are drawn here:
 * every other matrix (projections, the untied LM head): normal with
   standard deviation ``1 / sqrt(fan_in)``, ``fan_in`` being the input width.
 
+A model family may export ``draw(name, shape, dtype, key)`` for leaves this
+rule does not cover (a 1-D routing bias, say): it is asked first for every
+leaf, and where it returns None the rule above draws the leaf.
+
 Stacked per-layer leaves ``[L, ...]`` are drawn one layer at a time inside
 the call, so the float32 transient is one layer's leaf, not the stack's.
 """
@@ -45,9 +49,14 @@ def _draw(name: str, shape, dtype, key):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def make_params(abstract_tree, seed: int):
-    """Draw every leaf of ``abstract_tree`` (ShapeDtypeStructs) from ``seed``."""
+def make_params(abstract_tree, seed: int, draw=None):
+    """Draw every leaf of ``abstract_tree`` (ShapeDtypeStructs) from ``seed``,
+    asking the family's ``draw`` first where one is given."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract_tree)
+
+    def _pick(name, shape, dtype, key):
+        out = None if draw is None else draw(name, shape, dtype, key)
+        return _draw(name, shape, dtype, key) if out is None else out
 
     def gen(key):
         out = []
@@ -58,10 +67,10 @@ def make_params(abstract_tree, seed: int):
                 per = sds.shape[1:]
                 out.append(jax.lax.map(
                     lambda l, k=k, name=name, per=per, dt=sds.dtype:
-                    _draw(name, per, dt, jax.random.fold_in(k, l)),
+                    _pick(name, per, dt, jax.random.fold_in(k, l)),
                     jnp.arange(sds.shape[0])))
             else:
-                out.append(_draw(name, sds.shape, sds.dtype, k))
+                out.append(_pick(name, sds.shape, sds.dtype, k))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return jax.jit(gen)(key_from_seed(seed))

@@ -1,0 +1,2 @@
+"""Share of the traced window in which no operation ran on the device (%)."""
+from harness.layer import device_idle as read  # noqa: F401

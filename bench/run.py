@@ -9,7 +9,10 @@ One process on the cell's chips, in this order:
                      ``JAX_COMPILATION_CACHE_DIR`` says), every program kept
   2. device          the platform must be ``tpu`` with the cell's chips;
                      otherwise exit 2 and print no result
-  3. weights         made on the device from ``--seed`` in one jitted call
+  3. weights         made on the device from ``--seed`` in one jitted call,
+                     in the layout of the program's configuration that the
+                     model family (``bench/families/``) makes of the
+                     configuration file
   4. engine          the program's ``ServingEngine`` with the configuration
                      file's settings, behind its ``FrontDoor``
   5. warm-up         the decode program and every mixed tile width
@@ -18,7 +21,7 @@ One process on the cell's chips, in this order:
                      client side (with ``--trace 1``: profiled, and the
                      program's tracer on)
   8. correctness     a sample of finished requests, drawn from the seed,
-                     against the plain float32 reference
+                     against the family's plain float32 reference
   9. result          the last line of stdout, one JSON object
 
 Set-up (``setup_s``) is everything before the window opens, ramp included.
@@ -40,6 +43,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -48,7 +52,7 @@ sys.path.insert(0, BENCH)
 
 import numpy as np  # noqa: E402
 
-from harness import endtoend, flops  # noqa: E402
+from harness import endtoend  # noqa: E402
 from harness.spec import (Cell, SpecError, load_cell, metric_reader,  # noqa: E402
                           peaks)
 from harness.traffic import Traffic, check_fits  # noqa: E402
@@ -58,6 +62,10 @@ SAMPLE_TOKENS = 256        # served tokens the correctness sample reaches
 SAMPLE_MAX = 16            # requests in the sample at most
 REF_BATCH = 4              # sequences per group in the reference
 TRACER_CAPACITY = 1 << 20
+ENGINE_LOG = ("steps", "decode_steps", "mixed_dispatches", "decode_tokens",
+              "active_slot_steps", "slot_steps", "mixed_prefill_deferred",
+              "preempt_swap", "preempt_recompute", "host_plan_s",
+              "dispatch_sync_s", "gc_pause_s", "cow_forks")  # logged every run
 
 
 def log(msg: str) -> None:
@@ -66,6 +74,26 @@ def log(msg: str) -> None:
 
 class NoChip(RuntimeError):
     pass
+
+
+class Watchdog(threading.Thread):
+    """Wakes every ``tick`` seconds through the window and keeps its latest
+    wake-up: a stall it shares says the whole process stood still (held off
+    the CPU, or the interpreter lock held), one it does not says the main
+    thread waited inside a call."""
+
+    def __init__(self, tick: float = 0.01):
+        super().__init__(daemon=True)
+        self.tick, self.late, self.at = tick, 0.0, 0.0
+        self.stop = threading.Event()
+
+    def run(self):
+        t0 = t = time.perf_counter()
+        while not self.stop.wait(self.tick):
+            now = time.perf_counter()
+            if now - t - self.tick > self.late:
+                self.late, self.at = now - t - self.tick, t - t0
+            t = now
 
 
 class Clock:
@@ -100,10 +128,11 @@ def load_limits(workload: str) -> dict:
         return json.load(f)
 
 
-def draw_sample(recs, seed: int):
+def draw_sample(recs, seed: int, min_requests: int = 1):
     """Finished requests for the reference: the longest, then others drawn
-    from the seed, until ``SAMPLE_TOKENS`` served tokens or ``SAMPLE_MAX``
-    requests."""
+    from the seed, until ``SAMPLE_TOKENS`` served tokens and ``min_requests``
+    requests (the limits file's ``sample_requests``, so that the sample
+    spans several slots), or ``SAMPLE_MAX`` requests."""
     done = [r for r in recs if r.finished]
     if not done:
         return []
@@ -113,7 +142,8 @@ def draw_sample(recs, seed: int):
         len(rest))
     sample = [longest]
     for j in order:
-        if (sum(len(r.tokens) for r in sample) >= SAMPLE_TOKENS
+        if ((sum(len(r.tokens) for r in sample) >= SAMPLE_TOKENS
+                and len(sample) >= min_requests)
                 or len(sample) >= SAMPLE_MAX):
             break
         sample.append(rest[j])
@@ -178,8 +208,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     config, mix = cell.config, cell.traffic
     hf, eng_cfg = config["hf_config"], config["engine"]
     check_fits(mix, int(eng_cfg["max_len"]))
-    cfg = program.model_config(config)
-    params = weights.make_params(program.weight_layout(cfg), seed)   # 3
+    family = cell.family
+    cfg = family.model_config(config)
+    params = weights.make_params(program.weight_layout(cfg), seed,  # 3
+                                 draw=getattr(family, "draw", None))
     jax.block_until_ready(params)
     log(f"weights {time.perf_counter() - t_start:.2f} s after start")
 
@@ -200,6 +232,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         marks["open"] = time.perf_counter()
         marks["compiles"] = len(compiles)
         marks["stats0"] = dataclasses.asdict(engine.stats)
+        jax.config.update("jax_log_compiles", True)  # names what compiles late
+        marks["watchdog"] = Watchdog()
+        marks["watchdog"].start()
         if trace:
             jax.profiler.start_trace(trace_dir)
             marks["ann"] = jax.profiler.TraceAnnotation("bench/window")
@@ -208,6 +243,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     def on_close():
         marks["close"] = time.perf_counter()
         marks["compiles_in"] = len(compiles) - marks["compiles"]
+        jax.config.update("jax_log_compiles", False)
+        marks["watchdog"].stop.set()
+        marks["watchdog"].join()
         marks["stats1"] = dataclasses.asdict(engine.stats)
         if trace:
             marks["ann"].__exit__(None, None, None)
@@ -233,6 +271,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     stats = {k: marks["stats1"][k] - marks["stats0"][k]
              for k in marks["stats0"]
              if isinstance(marks["stats0"][k], (int, float))}
+    log("engine in the window: " + ", ".join(
+        f"{k} {stats.get(k, 0):.6g}" for k in ENGINE_LOG))
+    log(f"watchdog: latest wake-up {1e3 * marks['watchdog'].late:.1f} ms "
+        f"late, {marks['watchdog'].at:.2f} s into the window")
     spans = []
     if tracer is not None:
         if tracer.dropped_events:
@@ -249,19 +291,19 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     attempted = endtoend.attempted(recs, win)
     failed = endtoend.failed(recs, win)
-    sample = draw_sample(recs, seed)
+    limits = load_limits(cell.name) if limits is None else limits
+    sample = draw_sample(recs, seed, int(limits.get("sample_requests", 1)))
     del fd, engine, tracer                                           # 8
     gc.collect()
     t_ref = time.perf_counter()
     ref = None
     if sample:
         seqs = [(r.prompt, np.asarray(r.tokens, np.int32)) for r in sample]
-        ref = reference.logit_gaps(params, hf, seqs, controls,
-                                   batch=REF_BATCH)
+        ref = reference.logit_gaps(family.forward_rows, params, hf, seqs,
+                                   controls, batch=REF_BATCH)
     log(f"reference over {len(sample)} requests, "
         f"{sum(len(r.tokens) for r in sample)} served tokens: "
         f"{time.perf_counter() - t_ref:.2f} s")
-    limits = load_limits(cell.name) if limits is None else limits
     short = sum(1 for r in recs if r.state == "done" and not r.finished)
     checks, correct = compare(None if ref is None else ref["gap"], limits,
                               len(failed), short)
@@ -283,7 +325,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         record = SimpleNamespace(recs=recs, window=win, spans=spans,
                                  stats=stats, trace=red, hf=hf,
                                  engine=eng_cfg, peaks=peaks(info["kind"])
-                                 if require_chip else None, flops=flops)
+                                 if require_chip else None, flops=family)
         for m in cell.per_layer:
             v = metric_reader(m["name"])(record)
             if v is not None:

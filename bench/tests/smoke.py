@@ -1,6 +1,6 @@
 """A cell at smoke width for the CPU tests: the harness's phases run end to
 end with the Pallas kernels interpreted.  Nothing here is a device number."""
-from harness.spec import Cell
+from harness.spec import Cell, load_family
 
 HF = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
       "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -26,10 +26,12 @@ def cell(loop: str = "closed", hf=None) -> Cell:
             ("setup_s", "s"))]
     per_layer = [{"name": n, "unit": u} for n, u in
                  (("mixed_step_ms.poisson", "ms"), ("decode_step_ms.chat", "ms"),
-                  ("mixed_row_util.docs", "%"))]
+                  ("mixed_tile_util.docs", "%"))]
     return Cell(name="smoke", chips=1, config_name="smoke",
                 config={"hf_config": dict(hf or HF),
-                        "program": {"arch": "phi4-mini-3.8b"},
+                        "program": {"arch": "phi4-mini-3.8b",
+                                    "family": "dense_gqa"},
                         "engine": dict(ENGINE)},
+                family=load_family("dense_gqa"),
                 traffic_name="smoke", traffic=traffic, end_to_end=e2e,
                 per_layer=per_layer)

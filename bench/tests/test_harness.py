@@ -139,14 +139,53 @@ def test_command_fails_with_only_the_benchmark(tmp_path):
     assert "No module named 'repro'" in p.stderr
 
 
+FAMILY = ("model_config", "forward_rows", "token_flops", "prompt_flops",
+          "paged_attn_flops", "paged_attn_bytes")
+
+
 def test_benchmark_names_resolve():
-    """Every cell finds its configuration, traffic and metric readers."""
+    """Every cell finds its configuration, model family, traffic and metric
+    readers, and every metric entry is some cell's."""
     from harness.spec import load_cell, metric_reader
     with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
         bench = json.load(f)
+    used = set()
     for w in bench["workloads"]:
         cell = load_cell(w["name"])
         assert {"setup_s"} < {m["name"] for m in cell.end_to_end}
+        assert all(callable(getattr(cell.family, f)) for f in FAMILY)
         assert cell.per_layer
         for m in cell.per_layer:
             assert callable(metric_reader(m["name"]))
+            assert m["moves"] in {e["name"] for e in cell.end_to_end}
+        used |= {m["name"] for m in cell.end_to_end + cell.per_layer}
+    assert used == {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    assert "phi3medium-chat-closed" in {w["name"] for w in bench["workloads"]}
+
+
+def test_sample_spans_the_limits_files_floor_in_requests():
+    """The longest request alone can reach the token target; the sample
+    still takes others until it holds ``sample_requests`` of them."""
+    from types import SimpleNamespace as NS
+    recs = [NS(i=i, finished=True, prompt_len=8, max_new=n,
+               tokens=[0] * n) for i, n in enumerate([600] + [20] * 20)]
+    assert len(runmod.draw_sample(recs, SEED)) == 1
+    sample = runmod.draw_sample(recs, SEED, min_requests=8)
+    assert len(sample) == 8 and sample[0] is recs[0]
+    assert len({r.i for r in sample}) == 8
+    assert len(runmod.draw_sample(recs, SEED, 99)) == runmod.SAMPLE_MAX
+    with open(os.path.join(BENCH_DIR, "limits",
+                           "phi3medium-chat-closed.json")) as f:
+        assert json.load(f)["sample_requests"] > 1
+
+
+def test_warm_up_takes_the_copy_on_write_fork():
+    """A prompt whose first token equals a cached prompt's forks a block;
+    the warm-up takes that path, so its programs compile in set-up."""
+    from harness import program, weights
+    cell = smoke.cell()
+    cfg = cell.family.model_config(cell.config)
+    params = weights.make_params(program.weight_layout(cfg), SEED)
+    eng = program.build_engine(cfg, params, cell.config["engine"])
+    program.warm_up(eng, cell.config["engine"], SEED)
+    assert eng.prefix_sharing and eng.stats.cow_forks == 1

@@ -6,24 +6,15 @@ import os
 import numpy as np
 import pytest
 
-from harness import flops
-from harness.spec import BENCH_DIR
+from harness.spec import BENCH_DIR, load_family
 from harness.traffic import Traffic, lengths
 
 
-# phi3-medium at published widths, cut to 10 layers (Phi-3-medium-4k-instruct
-# config.json), beside the configurations the benchmark runs
-PHI3_MEDIUM_10L = {"hidden_size": 5120, "intermediate_size": 17920,
-                   "num_hidden_layers": 10, "num_attention_heads": 40,
-                   "num_key_value_heads": 10, "head_dim": 128,
-                   "vocab_size": 32064, "tie_word_embeddings": False}
-
-
-def _hf(name):
-    if name == "phi3-medium-14b":
-        return PHI3_MEDIUM_10L
+def _config(name):
+    """A configuration file's ``hf_config`` and the counts of its family."""
     with open(os.path.join(BENCH_DIR, "configs", name + ".json")) as f:
-        return json.load(f)["hf_config"]
+        config = json.load(f)
+    return config["hf_config"], load_family(config["program"]["family"])
 
 
 # hand counts at one context: the token at position 999 attends 1000 keys
@@ -47,7 +38,7 @@ HAND = {
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_flops_match_hand_count(name):
-    hf = _hf(name)
+    hf, flops = _config(name)
     mm, attn, head, kbytes = HAND[name]
     assert flops.layer_matmul_flops(hf) == mm
     assert flops.attention_flops(hf, 1000) == attn
