@@ -24,6 +24,7 @@ class AttnConfig:
     rope_theta: float = 10_000.0
     mrope_sections: Tuple[int, ...] = (16, 24, 24)   # t/h/w splits of d_head/2
     window: int = 0               # >0 ⇒ sliding-window attention
+    qk_norm: bool = False         # per-head RMSNorm of q and k before RoPE (Qwen3)
     # MLA (DeepSeek-V3) dims
     q_lora_rank: int = 0
     kv_lora_rank: int = 512
@@ -34,13 +35,23 @@ class AttnConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8            # router width: the experts every token is scored over
     top_k: int = 2
     d_ff: int = 1024              # per-expert hidden
     n_shared: int = 0             # shared (always-on) experts
     capacity_factor: float = 1.25
     aux_free_bias: bool = True    # DeepSeek-V3 aux-loss-free load balancing
     router_dtype: str = "float32"
+    # expert share (expert parallelism): this layer holds experts
+    # [held_start, held_start + n_held) of the router's n_experts and computes
+    # only their part of the output; n_held = 0 ⇒ all of them
+    n_held: int = 0
+    held_start: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts this layer holds weights for and computes."""
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)

@@ -47,6 +47,7 @@ __all__ = [
     "ngram_propose",
     "pageable_block",
     "speculable",
+    "counts_moe",
 ]
 
 
@@ -188,6 +189,37 @@ def _leaf_name(path) -> str:
     return jax.tree_util.keystr(path[-1:]).strip("[]'\"")
 
 
+def counts_moe(cfg: ModelConfig) -> bool:
+    """Whether the serving steps of ``cfg`` return, as their last output,
+    ``moe_held [n_moe_layers]`` int32: per MoE layer, the real rows' top-k
+    assignments that landed on the experts the layer holds.  Only the
+    device knows it; dense models carry no such output."""
+    return any(b.kind == "moe" for b in cfg.blocks)
+
+
+def _held_sum(held, real):
+    """``[n_moe_layers]``: Σ over the rows where ``real`` ([B, S],
+    broadcastable) of each layer's held assignments ``held [L, B, S]``."""
+    return jnp.sum(jnp.where(real, held, 0), axis=(1, 2), dtype=jnp.int32)
+
+
+def _forward(params, tokens, cfg: ModelConfig, real, **kw):
+    """``lm.forward`` for a serving step: (logits, caches, moe_held), the
+    last ``None`` unless :func:`counts_moe`, when it sums the rows where
+    ``real()`` ([B, S], broadcastable; traced only then, so a dense step's
+    program is what it was)."""
+    moe = counts_moe(cfg)
+    logits, caches, _, *held = lm.forward(params, tokens, cfg, moe_held=moe,
+                                          **kw)
+    return logits, caches, (_held_sum(held[0], real()) if moe else None)
+
+
+def _outs(*outs):
+    """A step's outputs, with its ``moe_held`` (the last) left out where the
+    model has no MoE layer."""
+    return outs if outs[-1] is not None else outs[:-1]
+
+
 def pageable_block(b: BlockConfig) -> bool:
     """Whether a segment's attention cache can use the paged block pool.
 
@@ -282,6 +314,7 @@ def make_slot_prefill_step(cfg: ModelConfig, max_len: int,
     table, never recomputed.
     ``slot``/``start`` are traced scalars so one executable serves every slot
     and chunk offset; only distinct chunk *lengths* compile separately.
+    MoE models (:func:`counts_moe`) append ``moe_held`` to the outputs.
     """
 
     def prefill_chunk(params, caches, tokens, slot, start, reset, tables=None,
@@ -304,10 +337,10 @@ def make_slot_prefill_step(cfg: ModelConfig, max_len: int,
         sl = jax.tree_util.tree_unflatten(treedef, sl)
         trow = (jax.lax.dynamic_slice_in_dim(tables, slot, 1, axis=0)
                 if paged else None)
-        logits, sl, _ = lm.forward(params, tokens, cfg, caches=sl,
-                                   patch_embeds=patch_embeds, pos3d=pos3d,
-                                   start_pos=start, moe_no_drop=True,
-                                   tables=trow)
+        logits, sl, held = _forward(params, tokens, cfg, lambda: True,
+                                    caches=sl, patch_embeds=patch_embeds,
+                                    pos3d=pos3d, start_pos=start,
+                                    moe_no_drop=True, tables=trow)
         out = []
         for (path, old), (_, new) in zip(
                 flat, jax.tree_util.tree_flatten_with_path(sl)[0]):
@@ -316,7 +349,8 @@ def make_slot_prefill_step(cfg: ModelConfig, max_len: int,
             else:
                 out.append(jax.lax.dynamic_update_slice_in_dim(
                     old, new, slot, axis=1))
-        return logits[:, -1], jax.tree_util.tree_unflatten(treedef, out)
+        return _outs(logits[:, -1], jax.tree_util.tree_unflatten(treedef, out),
+                     held)
 
     return prefill_chunk
 
@@ -363,15 +397,15 @@ def make_serving_decode_step(cfg: ModelConfig, top_k: int = 0,
     ``lengths`` must equal the per-slot cache ``pos`` (the scheduler's view
     of each slot's cached length).  ``sample=False`` compiles the pure greedy
     step (key/temperature accepted but unused); ``sample=True`` adds the
-    temperature + top-k path of :func:`_sample_tokens`.
+    temperature + top-k path of :func:`_sample_tokens`.  MoE models
+    (:func:`counts_moe`) append ``moe_held`` over the active slots.
     """
 
     def decode_step(params, caches, tokens, lengths, active, tables=None,
                     key=None, temperature=0.0):
-        nxt, caches = _masked_decode(params, caches, tokens, lengths, active,
+        return _outs(*_masked_decode(params, caches, tokens, lengths, active,
                                      tables, key if sample else None,
-                                     temperature, cfg, top_k)
-        return nxt, caches
+                                     temperature, cfg, top_k))
 
     return decode_step
 
@@ -409,7 +443,8 @@ def make_serving_mixed_step(cfg: ModelConfig, top_k: int = 0,
     have produced.  Pad rows write to the pool's write-off block.
     ``last_logits`` rides back to the host so the engine can emit first
     tokens of finishing prefills with the same host-side argmax/sampling it
-    uses on the separate path.
+    uses on the separate path.  MoE models (:func:`counts_moe`) append
+    ``moe_held`` over the rows that carry a token.
     """
 
     def mixed_step(params, caches, dec_tokens, lane_tokens, lengths, decode,
@@ -417,9 +452,11 @@ def make_serving_mixed_step(cfg: ModelConfig, top_k: int = 0,
         rows = MixedRows(decode, lane_slot, lane_lens)
         B = decode.shape[0]
         tokens = MixedRows.flat_tokens(dec_tokens, lane_tokens)
-        logits, caches, _ = lm.forward(params, tokens, cfg, caches=caches,
-                                       start_pos=lengths, moe_no_drop=True,
-                                       tables=tables, mixed=rows)
+        real = lambda: rows.real(tokens.shape[-1])
+        logits, caches, held = _forward(params, tokens, cfg, real,
+                                        caches=caches, start_pos=lengths,
+                                        moe_no_drop=True, tables=tables,
+                                        mixed=rows)
         logits = logits[0]                      # [B + L, V] or [B + L, K, V]
         # every slot's last real row: its decode row, or its lane's last row
         # (empty lanes are dropped)
@@ -427,7 +464,7 @@ def make_serving_mixed_step(cfg: ModelConfig, top_k: int = 0,
         last = logits[:B].at[dst].set(logits[B:], mode="drop")
         nxt = _sample_tokens(last[:, None], cfg, key if sample else None,
                              temperature, top_k)
-        return nxt, last, caches
+        return _outs(nxt, last, caches, held)
 
     return mixed_step
 
@@ -448,7 +485,8 @@ def make_serving_decode_guarded(cfg: ModelConfig, top_k: int = 0,
     step and keep their streams.  The argmax/sampling path is unchanged for
     finite rows, so emitted tokens match :func:`make_serving_decode_step`
     exactly; the guard costs one ``isfinite`` reduction per slot, paid only
-    by engines that opt into guarded decode.
+    by engines that opt into guarded decode.  MoE models
+    (:func:`counts_moe`) append ``moe_held`` over the active slots.
     """
 
     def decode_step(params, caches, tokens, lengths, active, tables=None,
@@ -456,9 +494,9 @@ def make_serving_decode_guarded(cfg: ModelConfig, top_k: int = 0,
         trash = _pool_trash_block(caches)
         if tables is not None and trash is not None:
             tables = jnp.where(active[:, None], tables, jnp.int32(trash))
-        logits, new_caches, _ = lm.forward(params, tokens, cfg, caches=caches,
-                                           start_pos=lengths[:, None],
-                                           moe_no_drop=True, tables=tables)
+        logits, new_caches, held = _forward(
+            params, tokens, cfg, lambda: active[:, None], caches=caches,
+            start_pos=lengths[:, None], moe_no_drop=True, tables=tables)
         if poison is not None:
             m = poison.reshape((-1,) + (1,) * (logits.ndim - 1))
             logits = jnp.where(m, jnp.nan, logits)
@@ -474,7 +512,7 @@ def make_serving_decode_guarded(cfg: ModelConfig, top_k: int = 0,
         caches = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
         nxt = _sample_tokens(logits, cfg, key if sample else None,
                              temperature, top_k)
-        return nxt, bad, caches
+        return _outs(nxt, bad, caches, held)
 
     return decode_step
 
@@ -482,13 +520,15 @@ def make_serving_decode_guarded(cfg: ModelConfig, top_k: int = 0,
 def _masked_decode(params, caches, tokens, lengths, active, tables, key,
                    temperature, cfg: ModelConfig, top_k: int):
     """One activity-masked decode over all slots (the shared body of the
-    single-step and horizon serving decode).  Returns (next_tokens, caches)."""
+    single-step and horizon serving decode).  Returns (next_tokens, caches,
+    moe_held), the last None unless :func:`counts_moe`."""
     trash = _pool_trash_block(caches)
     if tables is not None and trash is not None:
         tables = jnp.where(active[:, None], tables, jnp.int32(trash))
-    logits, new_caches, _ = lm.forward(params, tokens, cfg, caches=caches,
-                                       start_pos=lengths[:, None],
-                                       moe_no_drop=True, tables=tables)
+    logits, new_caches, held = _forward(params, tokens, cfg,
+                                        lambda: active[:, None], caches=caches,
+                                        start_pos=lengths[:, None],
+                                        moe_no_drop=True, tables=tables)
 
     def merge(path, old, new):
         if _leaf_name(path) in POOL_LEAVES:
@@ -498,7 +538,7 @@ def _masked_decode(params, caches, tokens, lengths, active, tables, key,
 
     caches = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
     nxt = _sample_tokens(logits, cfg, key, temperature, top_k)
-    return nxt, caches
+    return nxt, caches, held
 
 
 def make_serving_decode_horizon(cfg: ModelConfig, H: int, top_k: int = 0,
@@ -526,7 +566,8 @@ def make_serving_decode_horizon(cfg: ModelConfig, H: int, top_k: int = 0,
     decode-step counter at horizon entry: inner step ``h`` draws its sampling
     key as ``fold_in(key, step0 + h)``, the same schedule the single-step
     path uses, so a horizon run is token-identical to ``H`` single steps
-    (greedy always; sampled whenever the slot schedule matches).
+    (greedy always; sampled whenever the slot schedule matches).  MoE models
+    (:func:`counts_moe`) append ``moe_held`` summed over the inner steps.
     """
 
     def horizon_step(params, caches, tokens, lengths, active, remaining,
@@ -538,8 +579,9 @@ def make_serving_decode_horizon(cfg: ModelConfig, H: int, top_k: int = 0,
         def inner(carry, h):
             caches, tok, lengths, act, rem = carry
             k = jax.random.fold_in(key, step0 + h) if sample else None
-            nxt, caches = _masked_decode(params, caches, tok, lengths, act,
-                                         tables, k, temperature, cfg, top_k)
+            nxt, caches, held = _masked_decode(params, caches, tok, lengths,
+                                               act, tables, k, temperature,
+                                               cfg, top_k)
             # EOS on the first codebook (single-codebook: the token itself)
             first = nxt.reshape(B, -1)[:, 0]
             hit_eos = (eos_id >= 0) & (first == eos_id)
@@ -547,15 +589,16 @@ def make_serving_decode_horizon(cfg: ModelConfig, H: int, top_k: int = 0,
             lengths = lengths + act.astype(jnp.int32)
             new_act = act & (rem > 0) & ~hit_eos
             tok = jnp.where(act.reshape(tok_mask_shape), nxt, tok)
-            return (caches, tok, lengths, new_act, rem), (nxt, act)
+            return (caches, tok, lengths, new_act, rem), (nxt, act, held)
 
-        (caches, tok, lengths, act, rem), (toks, emitted) = jax.lax.scan(
+        (caches, tok, lengths, act, rem), (toks, emitted, held) = jax.lax.scan(
             inner, (caches, tokens, lengths, active, remaining),
             jnp.arange(H, dtype=jnp.int32))
         counts = emitted.astype(jnp.int32).sum(axis=0)              # [B]
         # toks: [H, B, 1] or [H, B, K, 1] → [B, H] / [B, K, H]
         block = jnp.moveaxis(toks[..., 0], 0, -1)
-        return block, counts, tok, caches
+        return _outs(block, counts, tok, caches,
+                     None if held is None else held.sum(0))
 
     return horizon_step
 
@@ -659,7 +702,9 @@ def make_serving_spec_horizon(cfg: ModelConfig, H: int, K: int,
     ``counts[s, h]`` is the number of valid tokens in ``token_block[s, h]``
     (0 once the slot froze); freezing is monotone over ``h``.  Greedy only:
     the accept rule compares argmaxes, so there is no sampling path here
-    (the engine enforces ``temperature == 0`` for speculation).
+    (the engine enforces ``temperature == 0`` for speculation).  MoE models
+    (:func:`counts_moe`) append ``moe_held`` summed over the inner steps,
+    every verify row of a live slot counted.
     """
     if K < 1:
         raise ValueError(f"spec draft length K must be >= 1, got {K}")
@@ -677,9 +722,10 @@ def make_serving_spec_horizon(cfg: ModelConfig, H: int, K: int,
             if tabs is not None and trash is not None:
                 tabs = jnp.where(act[:, None], tabs, jnp.int32(trash))
             tin = jnp.concatenate([tok, draft], axis=1)         # [B, K+1]
-            logits, new_caches, _ = lm.forward(
-                params, tin, cfg, caches=caches, start_pos=lengths[:, None],
-                moe_no_drop=True, tables=tabs, spec_decode=True)
+            logits, new_caches, held = _forward(
+                params, tin, cfg, lambda: act[:, None], caches=caches,
+                start_pos=lengths[:, None], moe_no_drop=True, tables=tabs,
+                spec_decode=True)
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, K+1]
             # longest accepted draft prefix: d_j must equal the argmax of the
             # logits one position earlier (the token that would have been
@@ -701,13 +747,14 @@ def make_serving_spec_horizon(cfg: ModelConfig, H: int, K: int,
             ext = jnp.concatenate([hist, g], axis=1)            # [B, W+K+1]
             hist = jnp.take_along_axis(
                 ext, m[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :], axis=1)
-            return (caches, tok, lengths, act, rem, hist), (g, m)
+            return (caches, tok, lengths, act, rem, hist), (g, m, held)
 
-        (caches, tok, lengths, act, rem, hist), (toks, counts) = jax.lax.scan(
-            inner, (caches, tokens, lengths, active, remaining, hist),
-            jnp.arange(H, dtype=jnp.int32))
+        (caches, tok, lengths, act, rem, hist), (toks, counts, held) = \
+            jax.lax.scan(inner, (caches, tokens, lengths, active, remaining,
+                                 hist), jnp.arange(H, dtype=jnp.int32))
         # toks: [H, B, K+1] → [B, H, K+1]; counts: [H, B] → [B, H]
-        return toks.swapaxes(0, 1), counts.T, tok, hist, caches
+        return _outs(toks.swapaxes(0, 1), counts.T, tok, hist, caches,
+                     None if held is None else held.sum(0))
 
     return spec_step
 

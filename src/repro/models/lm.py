@@ -72,8 +72,11 @@ def param_spec(cfg: ModelConfig) -> Dict:
 
 def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3d,
                    odin, remat: str, norm_eps: float, moe_no_drop: bool = False,
-                   tables=None, spec_decode: bool = False, mixed=None):
-    """Scan one homogeneous segment of layers over the sequence activations."""
+                   tables=None, spec_decode: bool = False, mixed=None,
+                   moe_held: bool = False):
+    """Scan one homogeneous segment of layers over the sequence activations.
+    ``moe_held`` (MoE segments): also return ``[n_layers, B, S]``, each
+    row's assignments to held experts per layer."""
     spec1 = block_spec(bcfg, x.shape[-1])
 
     def layer(x, inp):
@@ -86,13 +89,14 @@ def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3
             lambda w, s: constrain(w, s.logical_axes), p, spec1,
             is_leaf=lambda n: isinstance(n, ParamSpec),
         )
-        y, c2 = block_apply(p, x, bcfg, cache=c, positions=positions, pos3d=pos3d,
-                            odin=odin, norm_eps=norm_eps, moe_no_drop=moe_no_drop,
-                            tables=tables, spec_decode=spec_decode, mixed=mixed)
+        y, c2, *held = block_apply(
+            p, x, bcfg, cache=c, positions=positions, pos3d=pos3d, odin=odin,
+            norm_eps=norm_eps, moe_no_drop=moe_no_drop, tables=tables,
+            spec_decode=spec_decode, mixed=mixed, moe_held=moe_held)
         # pin the scanned activation sharding so carry propagation never
         # settles on "replicated" (no-op outside a logical_sharding context)
         y = constrain(y, ("batch", "act_seq", None))
-        return y, c2
+        return y, (c2, *held)
 
     if remat == "full":
         layer = jax.checkpoint(layer, prevent_cse=False)
@@ -101,13 +105,13 @@ def _segment_apply(params_stacked, x, bcfg: BlockConfig, caches, positions, pos3
             layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             prevent_cse=False,
         )
-    x, new_caches = jax.lax.scan(layer, x, (params_stacked, caches))
-    return x, new_caches
+    x, (new_caches, *held) = jax.lax.scan(layer, x, (params_stacked, caches))
+    return (x, new_caches, *held)
 
 
 def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
             pos3d=None, start_pos=None, moe_no_drop: bool = False, tables=None,
-            spec_decode: bool = False, mixed=None):
+            spec_decode: bool = False, mixed=None, moe_held: bool = False):
     """tokens: [B,S] (or [B,K,S] multi-codebook) → (logits, new_caches).
 
     logits: [B,S,V] (or [B,S,K,V]).  ``caches``: list of per-segment stacked
@@ -124,7 +128,10 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
     GQA caches only): ``tokens`` are its flat rows ``[1, N]`` (or
     ``[1, K, N]``), ``start_pos`` is the per-slot cached length [B] before
     the dispatch, and logits come back only for the rows that are read,
-    ``[1, B + L, V]`` (:meth:`MixedRows.head_rows`).
+    ``[1, B + L, V]`` (:meth:`MixedRows.head_rows`).  ``moe_held``: append
+    a fourth output, ``[n_moe_layers, B, S]`` int32, each row's top-k
+    assignments that landed on the experts a layer holds
+    (``MoEConfig.n_held``), every MoE layer in order.
     """
     odin = _odin(cfg)
     if cfg.n_codebooks > 1:
@@ -146,19 +153,24 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
     else:
         positions = start + jnp.arange(S, dtype=jnp.int32)[None, :] + jnp.zeros((B, 1), jnp.int32)
 
-    new_caches = []
+    new_caches, held = [], []
     for i, bcfg in enumerate(cfg.blocks):
         c = caches[i] if caches is not None else None
+        counted = moe_held and bcfg.kind == "moe"
         if c is None:
-            x, _ = _segment_apply(params["segments"][i], x, bcfg, None, positions, pos3d,
-                                  odin, cfg.remat, cfg.norm_eps, moe_no_drop)
+            x, _, *h = _segment_apply(params["segments"][i], x, bcfg, None,
+                                      positions, pos3d, odin, cfg.remat,
+                                      cfg.norm_eps, moe_no_drop,
+                                      moe_held=counted)
             new_caches.append(None)
         else:
-            x, c2 = _segment_apply(params["segments"][i], x, bcfg, c, positions, pos3d,
-                                   odin, cfg.remat, cfg.norm_eps, moe_no_drop,
-                                   tables=tables, spec_decode=spec_decode,
-                                   mixed=mixed)
+            x, c2, *h = _segment_apply(params["segments"][i], x, bcfg, c,
+                                       positions, pos3d, odin, cfg.remat,
+                                       cfg.norm_eps, moe_no_drop,
+                                       tables=tables, spec_decode=spec_decode,
+                                       mixed=mixed, moe_held=counted)
             new_caches.append(c2)
+        held += h
 
     hidden = x
     if mixed is not None:
@@ -169,7 +181,10 @@ def forward(params, tokens, cfg: ModelConfig, caches=None, patch_embeds=None,
         logits = jnp.einsum("bsd,kdv->bskv", x, head.astype(x.dtype))
     else:
         logits = jnp.matmul(x, head.astype(x.dtype))
-    return logits, (new_caches if caches is not None else None), hidden
+    out = (logits, (new_caches if caches is not None else None), hidden)
+    if moe_held:
+        return out + (jnp.concatenate(held),)
+    return out
 
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype=None,
@@ -255,8 +270,11 @@ def model_flops(cfg: ModelConfig, n_tokens: int, train: bool = True) -> float:
     inactive = 0
     for b in cfg.blocks:
         if b.kind == "moe" and b.moe is not None:
+            # of the experts held, a token reaches top_k·held/n_experts
             per_expert = 3 * cfg.d_model * b.moe.d_ff
-            inactive += b.n_layers * per_expert * (b.moe.n_experts - b.moe.top_k)
+            m = b.moe
+            inactive += b.n_layers * per_expert * (
+                m.held - m.top_k * m.held / m.n_experts)
     active = total - inactive
     mult = 6.0 if train else 2.0
     return mult * active * n_tokens

@@ -88,12 +88,16 @@ def attn_spec(cfg: AttnConfig, d_model: int) -> Dict[str, ParamSpec]:
         else:
             spec["q"] = linear_spec(d_model, H * qk_dim, ("embed", "heads_flat"))
         return spec
-    return {
+    spec = {
         "q": linear_spec(d_model, H * D, ("embed", "heads_flat")),
         "k": linear_spec(d_model, Hkv * D, ("embed", "heads_flat")),
         "v": linear_spec(d_model, Hkv * D, ("embed", "heads_flat")),
         "o": linear_spec(H * D, d_model, ("heads_flat", "embed")),
     }
+    if cfg.qk_norm:
+        spec["q_norm"] = ParamSpec((D,), (None,), jnp.float32, init="ones")
+        spec["k_norm"] = ParamSpec((D,), (None,), jnp.float32, init="ones")
+    return spec
 
 
 def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -429,12 +433,18 @@ def _paged_gqa_core(q, k, v, cfg: AttnConfig, positions, cache, tables,
 
 
 def _gqa_attention(p, x, cfg: AttnConfig, positions, pos3d, cache, odin,
-                   tables=None, spec_decode: bool = False, mixed=None):
+                   tables=None, spec_decode: bool = False, mixed=None,
+                   norm_eps: float = 1e-5):
     B, S, _ = x.shape
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = linear(x, p["q"], odin).reshape(B, S, H, D)
     k = linear(x, p["k"], odin).reshape(B, S, Hkv, D)
     v = linear(x, p["v"], odin).reshape(B, S, Hkv, D)
+    if cfg.qk_norm:
+        # per head, before RoPE: k is normed before it reaches the cache,
+        # q before any attention core (the paged kernel included)
+        q = rmsnorm(q, p["q_norm"], norm_eps)
+        k = rmsnorm(k, p["k_norm"], norm_eps)
     if cfg.rope == "mrope":
         if pos3d is None:
             # text-only / decode steps: M-RoPE degenerates to (t, t, t)
@@ -569,7 +579,8 @@ def _mla_attention(p, x, cfg: AttnConfig, positions, cache, odin):
 
 def attention(p, x, cfg: AttnConfig, positions=None, pos3d=None, cache=None,
               odin: Optional[OdinConfig] = None, tables=None,
-              spec_decode: bool = False, mixed: Optional[MixedRows] = None):
+              spec_decode: bool = False, mixed: Optional[MixedRows] = None,
+              norm_eps: float = 1e-5):
     """Returns (output [B,S,d_model], new_cache).  ``tables`` are the per-slot
     block tables of the paged serving cache (ignored by dense/MLA caches).
     ``spec_decode``: the S tokens are an in-flight speculative draft — paged
@@ -577,7 +588,8 @@ def attention(p, x, cfg: AttnConfig, positions=None, pos3d=None, cache=None,
     gather (dense/MLA caches already handle S > 1 with decode semantics).
     ``mixed``: the row layout of a mixed prefill+decode dispatch, whose
     rows are flat in ``x [1, N, d]`` (paged GQA caches only; ``positions``
-    then come from :meth:`MixedRows.positions`) — see :class:`MixedRows`."""
+    then come from :meth:`MixedRows.positions`) — see :class:`MixedRows`.
+    ``norm_eps``: epsilon of the per-head q/k RMSNorm (``cfg.qk_norm``)."""
     B, S, _ = x.shape
     if mixed is not None and (cache is None or "k_pool" not in cache):
         raise ValueError("mixed dispatch requires a paged GQA cache")
@@ -589,4 +601,5 @@ def attention(p, x, cfg: AttnConfig, positions=None, pos3d=None, cache=None,
     if cfg.kind == "mla":
         return _mla_attention(p, x, cfg, positions, cache, odin)
     return _gqa_attention(p, x, cfg, positions, pos3d, cache, odin, tables,
-                          spec_decode=spec_decode, mixed=mixed)
+                          spec_decode=spec_decode, mixed=mixed,
+                          norm_eps=norm_eps)

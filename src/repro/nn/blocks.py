@@ -98,13 +98,16 @@ def block_cache(cfg: BlockConfig, d_model: int, batch: int, max_len: int, dtype=
 def block_apply(p, x, cfg: BlockConfig, cache=None, positions=None, pos3d=None,
                 odin: Optional[OdinConfig] = None, norm_eps: float = 1e-5,
                 moe_no_drop: bool = False, tables=None,
-                spec_decode: bool = False, mixed=None):
+                spec_decode: bool = False, mixed=None,
+                moe_held: bool = False):
     """(params, x [B,S,d], cache) → (x', cache').  ``tables``: per-slot block
     tables when the attention cache is the paged block pool (serving);
     ``spec_decode``: the S tokens are a speculative draft tile (paged
     attention takes the multi-token-query kernel path); ``mixed``: the row
     layout (``nn.attention.MixedRows``) of a mixed prefill+decode dispatch
-    whose rows are flat in ``x [1, N, d]`` (paged GQA only)."""
+    whose rows are flat in ``x [1, N, d]`` (paged GQA only).  ``moe_held``
+    (MoE blocks): return ``(x', cache', held [B, S])``, each row's
+    assignments to the experts this layer holds."""
     new_cache = dict(cache) if cache is not None else None
     if mixed is not None and cfg.kind not in ("dense", "moe"):
         raise ValueError("mixed dispatch supports paged GQA blocks only")
@@ -112,23 +115,26 @@ def block_apply(p, x, cfg: BlockConfig, cache=None, positions=None, pos3d=None,
         a, ac = attention(p["attn"], rmsnorm(x, p["ln1"], norm_eps), cfg.attn,
                           positions=positions, pos3d=pos3d,
                           cache=None if cache is None else cache["attn"], odin=odin,
-                          tables=tables, spec_decode=spec_decode, mixed=mixed)
+                          tables=tables, spec_decode=spec_decode, mixed=mixed,
+                          norm_eps=norm_eps)
         x = x + a
         h = rmsnorm(x, p["ln2"], norm_eps)
-        if cfg.kind == "dense":
-            x = x + _mlp(p["mlp"], h, cfg.activation, odin)
-        else:
-            x = x + moe_block(p["moe"], h, cfg.moe, cfg.activation,
-                              no_drop=moe_no_drop, odin=odin)
         if new_cache is not None:
             new_cache["attn"] = ac
-        return x, new_cache
+        if cfg.kind == "dense":
+            return x + _mlp(p["mlp"], h, cfg.activation, odin), new_cache
+        y = moe_block(p["moe"], h, cfg.moe, cfg.activation,
+                      no_drop=moe_no_drop, odin=odin, with_held=moe_held)
+        if moe_held:
+            y, held = y
+            return x + y, new_cache, held
+        return x + y, new_cache
 
     if cfg.kind == "hymba":
         h = rmsnorm(x, p["ln1"], norm_eps)
         a, ac = attention(p["attn"], h, cfg.attn, positions=positions, pos3d=pos3d,
                           cache=None if cache is None else cache["attn"], odin=odin,
-                          tables=tables)
+                          tables=tables, norm_eps=norm_eps)
         s, sc = ssm_block(p["ssm"], h, cfg.ssm,
                           state=None if cache is None else cache["ssm"], odin=odin)
         # Hymba fusion: per-branch output norm, learnable per-channel mix

@@ -11,6 +11,14 @@ at the assigned shapes).
 Router: softmax over fp32 logits, top-k.  DeepSeek-style extensions: shared
 (always-on) experts and the aux-loss-free bias (a non-learned, per-expert
 bias added to routing scores only for *selection*, not for the gate weight).
+
+Expert share (expert parallelism): a layer may hold only a contiguous range
+of the router's experts (``MoEConfig.n_held`` from ``held_start``).  It
+still routes over all of them and normalises the top-k gates over all k
+picks, but dispatches only the assignments its own experts take — the
+others are dropped before dispatch and take no capacity — and returns the
+held experts' part of the output.  The shares of all chips add up to the
+whole layer; the exchange that would add them is not part of this layer.
 """
 from __future__ import annotations
 
@@ -29,12 +37,15 @@ __all__ = ["moe_spec", "moe_block", "dispatch_indices"]
 
 
 def moe_spec(cfg: MoEConfig, d_model: int) -> Dict[str, ParamSpec]:
-    E, F = cfg.n_experts, cfg.d_ff
+    E, Eh, F = cfg.n_experts, cfg.held, cfg.d_ff
+    if not (0 <= cfg.held_start and cfg.held_start + Eh <= E and cfg.top_k <= E):
+        raise ValueError(f"experts [{cfg.held_start}, {cfg.held_start + Eh}) "
+                         f"and top-{cfg.top_k} do not fit a router of {E}")
     spec = {
         "router": ParamSpec((d_model, E), ("embed", None), jnp.float32, init="fan_in"),
-        "w_gate": ParamSpec((E, d_model, F), ("experts", "embed", "mlp"), init="fan_in"),
-        "w_up": ParamSpec((E, d_model, F), ("experts", "embed", "mlp"), init="fan_in"),
-        "w_down": ParamSpec((E, F, d_model), ("experts", "mlp", "embed"), init="fan_in"),
+        "w_gate": ParamSpec((Eh, d_model, F), ("experts", "embed", "mlp"), init="fan_in"),
+        "w_up": ParamSpec((Eh, d_model, F), ("experts", "embed", "mlp"), init="fan_in"),
+        "w_down": ParamSpec((Eh, F, d_model), ("experts", "mlp", "embed"), init="fan_in"),
     }
     if cfg.aux_free_bias:
         spec["route_bias"] = ParamSpec((E,), (None,), jnp.float32, init="zeros")
@@ -53,6 +64,8 @@ def dispatch_indices(expert_ids: jax.Array, n_experts: int, capacity: int):
     ``slot = expert·C + rank`` for kept assignments (rank < capacity) and
     the out-of-bounds sentinel ``E·C`` for dropped ones — scatters must use
     ``mode="drop"`` (a 0 sentinel would clobber expert 0's first slot).
+    An id of ``n_experts`` (an expert not held here) sorts after every held
+    one, so it takes no rank of theirs, and is dropped.
     """
     A = expert_ids.shape[0]
     order = jnp.argsort(expert_ids, stable=True)               # assignments grouped by expert
@@ -61,28 +74,42 @@ def dispatch_indices(expert_ids: jax.Array, n_experts: int, capacity: int):
     starts = jnp.searchsorted(sorted_ids, jnp.arange(n_experts), side="left")
     rank_sorted = jnp.arange(A, dtype=jnp.int32) - starts[sorted_ids]
     rank = jnp.zeros((A,), jnp.int32).at[order].set(rank_sorted)
-    keep = rank < capacity
+    keep = (rank < capacity) & (expert_ids < n_experts)
     slot = jnp.where(keep, expert_ids * capacity + rank, n_experts * capacity)
     return slot, keep
 
 
 def moe_block(p, x: jax.Array, cfg: MoEConfig, activation_kind: str = "swiglu",
-              no_drop: bool = False,
-              odin: Optional[OdinConfig] = None) -> jax.Array:
-    """x: [B, S, d] → [B, S, d]."""
+              no_drop: bool = False, odin: Optional[OdinConfig] = None,
+              with_held: bool = False):
+    """x: [B, S, d] → [B, S, d]: the held experts' part of the layer output
+    (the whole output when every expert is held).  ``with_held`` also
+    returns ``[B, S]`` int32: how many of each row's top-k assignments
+    landed on a held expert."""
     B, S, d = x.shape
     T = B * S
     xt = x.reshape(T, d)
+    E, Eh, k = cfg.n_experts, cfg.held, cfg.top_k
 
-    logits = (xt.astype(jnp.float32) @ p["router"])            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    select_scores = probs + (p["route_bias"][None, :] if "route_bias" in p else 0.0)
-    _, top_idx = jax.lax.top_k(select_scores, cfg.top_k)       # [T, k]
-    gates = jnp.take_along_axis(probs, top_idx, axis=-1)       # gate from *unbiased* probs
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe/route"):
+        logits = (xt.astype(jnp.float32) @ p["router"])        # [T, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        select_scores = probs + (p["route_bias"][None, :] if "route_bias" in p else 0.0)
+        _, top_idx = jax.lax.top_k(select_scores, k)           # [T, k]
+        gates = jnp.take_along_axis(probs, top_idx, axis=-1)   # gate from *unbiased* probs
+        # normalised over all k picks, held here or not
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        A = T * k
+        expert_ids = top_idx.reshape(A).astype(jnp.int32)
+        if Eh != E:
+            # local ids of the held range; the rest become Eh and are dropped
+            expert_ids = expert_ids - cfg.held_start
+            expert_ids = jnp.where((expert_ids >= 0) & (expert_ids < Eh),
+                                   expert_ids, Eh)
+        held = None
+        if with_held:
+            held = (expert_ids < Eh).reshape(T, k).sum(-1, dtype=jnp.int32)
 
-    A = T * cfg.top_k
-    expert_ids = top_idx.reshape(A).astype(jnp.int32)
     if no_drop or S == 1:
         # Inference-exact routing: capacity overflow resolves in batch order,
         # so a dropped assignment couples one token's output to what else
@@ -98,47 +125,53 @@ def moe_block(p, x: jax.Array, cfg: MoEConfig, activation_kind: str = "swiglu",
         # the serving prefill path sets it and bounds T by the chunk length.
         capacity = T
     else:
-        capacity = max(1, int(T * cfg.top_k * cfg.capacity_factor / cfg.n_experts))
-    slot, keep = dispatch_indices(expert_ids, cfg.n_experts, capacity)
+        capacity = max(1, int(T * k * cfg.capacity_factor / E))
 
-    token_idx = jnp.repeat(jnp.arange(T, dtype=jnp.int32), cfg.top_k)
-    # Dispatch in GATHER form: scatter only scalar assignment ids into the
-    # slot table, then gather token rows.  A row-wise ``buf.at[slot].set(x)``
-    # scatter lowers to index matrices materialized at [E·C, d] (≈240 GB u32
-    # at the 671B train cell); the scalar scatter is [E·C] ints.
-    slot_to_assign = jnp.full((cfg.n_experts * capacity,), A, jnp.int32)
-    slot_to_assign = slot_to_assign.at[slot].set(
-        jnp.where(keep, jnp.arange(A, dtype=jnp.int32), A), mode="drop")
-    token_for_slot = jnp.concatenate([token_idx, jnp.zeros((1,), jnp.int32)])[slot_to_assign]
-    filled = (slot_to_assign < A)[:, None]
-    buf = jnp.where(filled, xt[token_for_slot], 0)
-    buf = buf.reshape(cfg.n_experts, capacity, d)
-    # EP sharding hint: experts on "model", capacity on data — keeps the
-    # [E, C, d] buffer (≈150 GB at the 671B train cell) distributed instead
-    # of replicated (no-op outside a logical_sharding context).
-    buf = constrain(buf, ("experts", "capacity", None))
+    with jax.named_scope("moe/experts"):
+        slot, keep = dispatch_indices(expert_ids, Eh, capacity)
+        token_idx = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        # Dispatch in GATHER form: scatter only scalar assignment ids into
+        # the slot table, then gather token rows.  A row-wise
+        # ``buf.at[slot].set(x)`` scatter lowers to index matrices
+        # materialized at [E·C, d] (≈240 GB u32 at the 671B train cell); the
+        # scalar scatter is [E·C] ints.
+        slot_to_assign = jnp.full((Eh * capacity,), A, jnp.int32)
+        slot_to_assign = slot_to_assign.at[slot].set(
+            jnp.where(keep, jnp.arange(A, dtype=jnp.int32), A), mode="drop")
+        token_for_slot = jnp.concatenate([token_idx, jnp.zeros((1,), jnp.int32)])[slot_to_assign]
+        filled = (slot_to_assign < A)[:, None]
+        buf = jnp.where(filled, xt[token_for_slot], 0)
+        buf = buf.reshape(Eh, capacity, d)
+        # EP sharding hint: experts on "model", capacity on data — keeps the
+        # [E, C, d] buffer (≈150 GB at the 671B train cell) distributed
+        # instead of replicated (no-op outside a logical_sharding context).
+        buf = constrain(buf, ("experts", "capacity", None))
 
-    # per-expert FFN — grouped einsums; 'experts' axis shards (EP)
-    g = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"].astype(x.dtype))
-    u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"].astype(x.dtype))
-    if activation_kind == "swiglu":
-        h = jax.nn.silu(g) * u
-    elif activation_kind == "relu2":
-        r = jax.nn.relu(g)
-        h = r * r * u
-    else:
-        h = jax.nn.gelu(g) * u
-    y = jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(x.dtype))
-    y = y.reshape(cfg.n_experts * capacity, d)
+        # per-expert FFN — grouped einsums; 'experts' axis shards (EP)
+        g = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"].astype(x.dtype))
+        u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"].astype(x.dtype))
+        if activation_kind == "swiglu":
+            h = jax.nn.silu(g) * u
+        elif activation_kind == "relu2":
+            r = jax.nn.relu(g)
+            h = r * r * u
+        else:
+            h = jax.nn.gelu(g) * u
+        y = jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(x.dtype))
+        y = y.reshape(Eh * capacity, d)
 
-    # combine: gather each assignment's expert output, weight by gate, sum over k
-    out_per_assign = jnp.where(keep[:, None], y[slot], 0)      # [A, d]
-    out_per_assign = constrain(out_per_assign, ("capacity", None))
-    weighted = out_per_assign * gates.reshape(A, 1).astype(x.dtype)
-    out = jax.ops.segment_sum(weighted, token_idx, num_segments=T)
-    out = constrain(out, ("capacity", None))
+        # combine: gather each assignment's expert output, weight by gate,
+        # sum over k
+        out_per_assign = jnp.where(keep[:, None], y[slot], 0)  # [A, d]
+        out_per_assign = constrain(out_per_assign, ("capacity", None))
+        weighted = out_per_assign * gates.reshape(A, 1).astype(x.dtype)
+        out = jax.ops.segment_sum(weighted, token_idx, num_segments=T)
+        out = constrain(out, ("capacity", None))
 
     if "shared_gate" in p:
         sg = jax.nn.silu(linear(xt, p["shared_gate"], odin)) * linear(xt, p["shared_up"], odin)
         out = out + linear(sg, p["shared_down"], odin)
-    return out.reshape(B, S, d)
+    out = out.reshape(B, S, d)
+    if with_held:
+        return out, held.reshape(B, S)
+    return out

@@ -456,6 +456,12 @@ class ServingEngine:
         self.sched.defer_prefix_register = self.mixed
         self.stats = EngineStats()
         self.stats.kv_cache_bytes = self._kv_bytes()
+        # MoE counters: per row a dispatch carries, the assignments routed
+        # (top_k per MoE layer) and the expert rows computed (every held
+        # expert runs drop-free capacity, one row per dispatched row)
+        moe = [b for b in cfg.blocks if b.kind == "moe"]
+        self._moe_routed = sum(b.n_layers * b.moe.top_k for b in moe)
+        self._moe_rows = sum(b.n_layers * b.moe.held for b in moe)
         self.cost_model = OdinCostModel(attribution_cfg or cfg)
         # observability: structured tracer (no-op by default — every emit
         # site is guarded on tracer.enabled so trace-off costs nothing) and
@@ -563,7 +569,27 @@ class ServingEngine:
                 "pool_writes": st.pool_writes,
                 "retired_blocks": st.retired_blocks,
                 "scrub_copies": st.scrub_copies,
-                "scrub_rows": st.scrub_rows}
+                "scrub_rows": st.scrub_rows,
+                "moe_routed": st.moe_routed,
+                "moe_held": st.moe_held,
+                "moe_rows": st.moe_rows}
+
+    def _count_moe(self, held, routed_rows: int, rows: int) -> None:
+        """MoE counters of one dispatch: ``held`` the step's ``moe_held``
+        outputs (host copies; none for a dense model), ``routed_rows`` the
+        rows that carried a token, ``rows`` the rows the program ran."""
+        if not held:
+            return
+        st = self.stats
+        n = int(sum(int(np.sum(h)) for h in held))
+        st.moe_held += n
+        st.moe_routed += routed_rows * self._moe_routed
+        st.moe_rows += rows * self._moe_rows
+        if self.tracer.enabled:
+            self.tracer.counter("moe held", "moe",
+                                {"held": n,
+                                 "routed": routed_rows * self._moe_routed,
+                                 "rows": rows * self._moe_rows})
 
     def _set_last_tok(self, slot: int, tok) -> None:
         tok = jnp.asarray(tok, jnp.int32).reshape(self._last_tok.shape[1:])
@@ -1063,6 +1089,7 @@ class ServingEngine:
             tables = self._refresh_tables()
         start = start0
         ll = None
+        held = []
         with self._phase("prefill"):
             while start < ntok:
                 c = min(self.chunk, ntok - start)
@@ -1073,15 +1100,17 @@ class ServingEngine:
                         kw["patch_embeds"] = jnp.asarray(extras["patch_embeds"])[None]
                     if pos3d is not None:
                         kw["pos3d"] = jnp.asarray(pos3d)[None][:, start:start + c]
-                ll, self.caches = self._prefill(
+                ll, self.caches, *h = self._prefill(
                     self.params, self.caches, chunk_toks,
                     jnp.int32(req.slot), jnp.int32(start), jnp.bool_(start == start0),
                     tables, **kw)
+                held += h
                 self.stats.dispatches += 1
                 chunk_sizes.append(c)
                 start += c
         with self._phase("sync"):
             jax.block_until_ready(ll)
+            held = jax.device_get(held)
             if fresh:
                 ll = np.asarray(ll)          # the first token's logits
         wall = self._now() - t0
@@ -1093,6 +1122,7 @@ class ServingEngine:
             self.stats.host_syncs += 1
             self.stats.prefill_time += wall
             self.stats.prefill_tokens += ntok - start0
+            self._count_moe(held, ntok - start0, ntok - start0)
             req.n_prefill_tokens += ntok - start0
             self.metrics.observe("dispatch_prefill_s", wall)
             if trace:
@@ -1216,14 +1246,14 @@ class ServingEngine:
             tables = self._refresh_tables()
         with self._phase("mixed"):
             key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-            nxt, last_logits, self.caches = self._mixed_fn()(
+            nxt, last_logits, self.caches, *held = self._mixed_fn()(
                 self.params, self.caches, jnp.asarray(dec_tok),
                 jnp.asarray(lane_tok), jnp.asarray(self._slot_len),
                 jnp.asarray(dm),
                 jnp.asarray(lane_slot), jnp.asarray(lane_lens), tables, key,
                 jnp.float32(self.temperature))
         with self._phase("sync"):
-            host = np.asarray(nxt)                   # syncs the step
+            host, held = jax.device_get((nxt, held))  # syncs the step
             ll_host = np.asarray(last_logits) if parts else None
         wall = self._now() - t0
         now = self._now()
@@ -1248,6 +1278,7 @@ class ServingEngine:
             self.stats.mixed_prefill_rows += pre_rows
             self.stats.mixed_tile_rows += n_rows
             self.stats.mixed_prefill_deferred += self.sched.lane_deferred
+            self._count_moe(held, rows, n_rows)
             if self.tracer.enabled:
                 self.tracer.span(
                     "mixed", "dispatch", "dispatch", t0, wall,
@@ -1507,12 +1538,12 @@ class ServingEngine:
             tables = self._refresh_tables()  # growth may have extended them
         with self._phase("decode"):
             key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-            nxt, self.caches = self._decode(
+            nxt, self.caches, *held = self._decode(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 tables, key, jnp.float32(self.temperature))
         with self._phase("sync"):
-            host = np.asarray(nxt)                   # syncs the step
+            host, held = jax.device_get((nxt, held))  # syncs the step
         wall = self._now() - t0
         now = self._now()
         with self._phase("wear"):
@@ -1521,6 +1552,7 @@ class ServingEngine:
                                     int(self._slot_len[s]), 1, now)
         with self._phase("emit"):
             self._decode_account(active_slots, active, nxt, t0, wall)
+            self._count_moe(held, len(active_slots), self.slots)
             for s in active_slots:
                 req = self.sched.running[s]
                 self._slot_len[s] += 1
@@ -1592,14 +1624,13 @@ class ServingEngine:
             tables = self._refresh_tables()
         with self._phase("decode"):
             key = jax.random.fold_in(self._sample_key, self.stats.decode_steps)
-            nxt, bad, self.caches = self._guarded_fn()(
+            nxt, bad, self.caches, *held = self._guarded_fn()(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 tables, key, jnp.float32(self.temperature),
                 jnp.asarray(poison))
         with self._phase("sync"):
-            host = np.asarray(nxt)                   # syncs the step
-            badh = np.asarray(bad)
+            host, badh, held = jax.device_get((nxt, bad, held))  # syncs
         wall = self._now() - t0
         now = self._now()
         with self._phase("wear"):
@@ -1611,6 +1642,7 @@ class ServingEngine:
         with self._phase("emit"):
             self._decode_account(active_slots, active, nxt, t0, wall,
                                  guarded=True)
+            self._count_moe(held, len(active_slots), self.slots)
             for s in active_slots:
                 req = self.sched.running[s]
                 if badh[s]:
@@ -1642,7 +1674,7 @@ class ServingEngine:
         with self._phase("tables"):
             tables = self._refresh_tables()
         with self._phase("horizon"):
-            block, counts, last, self.caches = self._horizon_fn(h)(
+            block, counts, last, self.caches, *held = self._horizon_fn(h)(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 jnp.asarray(rem), tables, self._sample_key,
@@ -1650,7 +1682,7 @@ class ServingEngine:
                 jnp.int32(self.stats.decode_steps),
                 jnp.int32(-1 if self.eos_id is None else self.eos_id))
         with self._phase("sync"):
-            block, counts = jax.device_get((block, counts))  # ONE sync, h steps
+            block, counts, held = jax.device_get((block, counts, held))  # ONE sync, h steps
         wall = self._now() - t_before
         now_w = self._now()
         with self._phase("wear"):
@@ -1677,6 +1709,7 @@ class ServingEngine:
             self.stats.host_syncs += 1
             self.stats.active_slot_steps += emitted
             self.stats.slot_steps += self.slots * h
+            self._count_moe(held, emitted, self.slots * h)
             self._last_tok = last
             span = wall                          # engine-clock dispatch span
             for hh in range(h):                  # step-major: matches h=1 order
@@ -1717,13 +1750,13 @@ class ServingEngine:
         with self._phase("tables"):
             tables = self._refresh_tables()
         with self._phase("spec-horizon"):
-            block, counts, last, hist, self.caches = self._fused_fn(h, K)(
+            block, counts, last, hist, self.caches, *held = self._fused_fn(h, K)(
                 self.params, self.caches, self._last_tok,
                 jnp.asarray(self._slot_len), jnp.asarray(active),
                 jnp.asarray(rem), self._hist, tables,
                 jnp.int32(-1 if self.eos_id is None else self.eos_id))
         with self._phase("sync"):
-            block, counts = jax.device_get((block, counts))   # ONE sync
+            block, counts, held = jax.device_get((block, counts, held))  # ONE sync
         wall = self._now() - t_before
         now_w = self._now()
         live = counts > 0                                  # [slots, h]
@@ -1759,6 +1792,7 @@ class ServingEngine:
             emitted = int(counts.sum())
             rows = (K + 1) * int(live.sum())
             self.stats.spec_overhead_rows += rows - emitted
+            self._count_moe(held, rows, self.slots * (K + 1) * h)
             for s in active_slots:
                 s_over = int(((K + 1) * live[s] - counts[s]).sum())
                 if s_over:

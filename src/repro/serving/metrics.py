@@ -56,6 +56,9 @@ class EngineStats:
     mixed_prefill_rows: int = 0           # prefill rows carried by mixed tiles
     mixed_tile_rows: int = 0              # rows the mixed program ran (slots + lanes·Q)
     mixed_prefill_deferred: int = 0       # mixed dispatches the lane cap held a prompt back
+    moe_routed: int = 0                   # real rows × top_k × MoE layers
+    moe_held: int = 0                     # of those, assignments to held experts (device count)
+    moe_rows: int = 0                     # expert rows computed: held × capacity × MoE layers
     swap_skipped_blocks: int = 0          # swap-out copies skipped (re-attach)
     jit_evictions: int = 0                # fused executables dropped (LRU)
     timeouts: int = 0                     # requests expired (deadline/queue)

@@ -69,3 +69,21 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
         sds((B, PAGES), jnp.int32),
         sds((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_compiles_for_v5e_at_qwen3_moe_widths(
+        one_chip, no_persistent_cache):
+    """Qwen3-235B-A22B's attention: 64 query heads over 4 kv heads of 128
+    (16 query heads a kv head, a [4, 128] page tile), 48 slots of 2048."""
+    B, H, HKV, PAGES = 48, 64, 4, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    n_blocks = 6144 + 1
+    fn = jax.jit(lambda q, k, v, t, n: paged_attention(
+        q, k, v, t, n, interpret=False))
+    compiled = fn.lower(
+        sds((B, H, D), jnp.bfloat16),
+        sds((n_blocks, BS, HKV, D), jnp.bfloat16),
+        sds((n_blocks, BS, HKV, D), jnp.bfloat16),
+        sds((B, PAGES), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
